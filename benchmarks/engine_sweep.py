@@ -65,6 +65,7 @@ import jax
 from repro import engine
 from repro.core import datasets
 from repro.core.protocols import kparty
+from repro.launch import compile_cache
 from repro.launch.mesh import make_data_mesh
 
 from benchmarks import _timing as timing
@@ -321,4 +322,5 @@ def main(tiny: bool = False, devices: int = 8) -> List[str]:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main(tiny=_ARGS.tiny, devices=_ARGS.devices)

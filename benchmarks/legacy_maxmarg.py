@@ -40,10 +40,12 @@ def kparty_maxmarg_hostloop(
     eps: float = 0.05,
     max_epochs: int = 48,
     max_support: int = 4,
+    fit=clf.fit_max_margin,
 ) -> ProtocolResult:
     """The retired k-party MAXMARG host loop (paper §7 variant): the epoch
     coordinator fits on everything it knows, broadcasts support points, and
-    the others reply with their own most-violated points."""
+    the others reply with their own most-violated points.  ``fit`` is the
+    max-margin solver, ``(X, y) -> LinearSeparator``."""
     nodes, log = make_nodes(shards)
     k = len(nodes)
     n_total = sum(nd.n for nd in nodes)
@@ -55,7 +57,7 @@ def kparty_maxmarg_hostloop(
             log.new_round()
             coord = nodes[ci]
             X, y = coord.all_known()
-            h = clf.fit_max_margin(X, y)
+            h = fit(X, y)
             sidx = clf.support_points(h, X, y, max_support=max_support)
             errs = []
             for nd in nodes:

@@ -42,6 +42,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from repro import engine
 from repro.core import datasets
 from repro.core.protocols import kparty
+from repro.launch import compile_cache
 
 from benchmarks import _timing as timing
 from benchmarks.legacy_maxmarg import kparty_maxmarg_hostloop
@@ -282,4 +283,5 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--tiny", action="store_true",
                     help="CI smoke sizes (small shards, 1 repeat)")
+    compile_cache.enable()
     main(tiny=ap.parse_args().tiny)

@@ -11,7 +11,8 @@ Sections:
   kernel_bench    data-plane hot-loop timings
   roofline_table  §Roofline terms from the dry-run artifacts (if present)
 
-Prints a final ``name,us_per_call,derived`` CSV block.
+Prints a final ``name,us_per_call,derived`` CSV block, and exits non-zero
+when any section failed (the others still run).
 """
 
 from __future__ import annotations
@@ -27,8 +28,9 @@ from benchmarks import comm_scaling, engine_sweep, kernel_bench, lower_bound
 from benchmarks import maxmarg_sweep, paper_tables, roofline_table
 
 
-def main() -> None:
+def main() -> int:
     csv: List[str] = []
+    failed: List[str] = []
     sections = [
         ("paper tables (2/3/4)", paper_tables.main),
         ("communication scaling (Table 1 rates)", comm_scaling.main),
@@ -41,16 +43,18 @@ def main() -> None:
         print(f"\n{'=' * 72}\n== {title}\n{'=' * 72}")
         try:
             csv += fn() or []
-        except Exception:  # noqa: BLE001 — keep the suite running
+        except Exception:  # noqa: BLE001 — run the rest, fail at the end
             traceback.print_exc()
             csv.append(f"{title},0,ERROR")
+            failed.append(title)
     if os.path.exists(roofline_table.RESULTS):
         for mesh in ("single", "multi"):
             print(f"\n{'=' * 72}\n== roofline ({mesh})\n{'=' * 72}")
             try:
                 csv += roofline_table.main(mesh) or []
-            except Exception:
+            except Exception:  # noqa: BLE001
                 traceback.print_exc()
+                failed.append(f"roofline ({mesh})")
     else:
         print("\n(no dryrun.jsonl — run `python -m repro.launch.dryrun` for the "
               "roofline section)")
@@ -59,7 +63,11 @@ def main() -> None:
     print("name,us_per_call,derived")
     for line in csv:
         print(line)
+    if failed:
+        print(f"\nFAILED sections: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

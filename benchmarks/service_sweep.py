@@ -69,6 +69,7 @@ from repro.engine import hotloop, median, run_instances, session_pool, unified
 from repro.engine.faults import FaultSchedule
 from repro.engine.session_pool import PoolConfig, SessionPool
 from repro.engine.state import ProtocolInstance
+from repro.launch import compile_cache
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OUT = os.path.join(ROOT, "BENCH_service.json")
@@ -444,5 +445,6 @@ if __name__ == "__main__":
     ap.add_argument("--tiny", action="store_true",
                     help="CI chaos-smoke sizes (24 sessions, 8 slots)")
     args = ap.parse_args()
+    compile_cache.enable()
     for line in main(tiny=args.tiny):
         print(line)

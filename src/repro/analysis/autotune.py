@@ -10,10 +10,11 @@ and deterministic:
    the same entry as their padded siblings;
 2. the committed cache ``src/repro/kernels/tuning_cache.json`` is consulted
    with the key ``"{device_kind}|B{bB}_N{bN}_d{bd}"``;
-3. on a miss (unknown device, untuned bucket, or a deleted cache file) the
-   deterministic fallback table applies — keyed by device kind and the
-   d bucket only, so behaviour off the tuned grid is still reproducible
-   and documented rather than an accident of search order.
+3. on a miss (untuned bucket or a deleted cache file) the deterministic
+   fallback table applies — keyed by device family and the d bucket only,
+   so behaviour off the tuned grid is still reproducible and documented
+   rather than an accident of search order.  A device family with no
+   table (an accelerator other than a TPU) is an error, not CPU tiles.
 
 ``unroll`` only affects the jnp ref twin's ``fori_loop`` (the CPU fast
 path); ``block_b``/``block_n`` only affect the Pallas launch.  Both live in
@@ -69,7 +70,6 @@ _FALLBACK: Dict[str, Tuple[Tuple[int, TileConfig], ...]] = {
         (0, TileConfig(block_b=4, block_n=128, unroll=1)),
     ),
 }
-_DEFAULT_KIND = "cpu"
 
 
 def _bucket_pow2(x: int, floor: int) -> int:
@@ -88,13 +88,15 @@ def cache_key(device_kind: str, B: int, N: int, d: int) -> str:
 
 
 def _normalize_kind(device_kind: str) -> str:
-    """Map a jax ``device_kind`` string to a fallback-table family."""
+    """Map a jax ``device_kind`` string to a fallback-table family; raises
+    for a device with no table."""
     kind = device_kind.lower()
     if "tpu" in kind:
         return "tpu"
     if kind in _FALLBACK:
         return kind
-    return _DEFAULT_KIND
+    raise ValueError(f"no Pegasos tile table for device kind "
+                     f"{device_kind!r} (tables: {sorted(_FALLBACK)})")
 
 
 @functools.lru_cache(maxsize=1)
@@ -211,26 +213,25 @@ def search_bucket(B: int, N: int, d: int, *, nsteps: int = 200,
          for c in cfgs), key=lambda t: t[0])
     best_s, best = scored[0]
 
-    # cost model of the winner, recorded alongside the measurement
-    fn = jax.jit(functools.partial(
-        ref.pegasos_stage_batch_ref, nsteps=nsteps, unroll=best.unroll))
-    compiled = fn.lower(X, y, nv, w, b, lam, found, wb, bb0).compile()
-    report = roofline.analyze_compiled(
-        f"pegasos_B{B}_N{N}_d{d}", compiled, chips=1)
-    model_s = max(report.compute_s, report.memory_s, report.collective_s)
-    intensity = report.flops / max(report.bytes_accessed, 1.0)
-    return {
-        "key": cache_key(jax.devices()[0].device_kind, B, N, d),
-        "entry": {
-            **asdict(best),
-            "score_us": best_s * 1e6,
-            "nsteps": nsteps,
-            "measured_path": "pallas" if on_tpu else "ref",
-            "roofline": {"dominant": report.dominant,
-                         "intensity": round(intensity, 3),
-                         "model_us": model_s * 1e6},
-        },
-    }
+    kind = jax.devices()[0].device_kind
+    entry = {**asdict(best), "score_us": best_s * 1e6, "nsteps": nsteps,
+             "measured_path": "pallas" if on_tpu else "ref"}
+    if on_tpu:
+        # cost model of the winner against the chip's published peaks,
+        # recorded alongside the measurement
+        fn = jax.jit(functools.partial(
+            ref.pegasos_stage_batch_ref, nsteps=nsteps, unroll=best.unroll))
+        compiled = fn.lower(X, y, nv, w, b, lam, found, wb, bb0).compile()
+        report = roofline.analyze_compiled(
+            f"pegasos_B{B}_N{N}_d{d}", compiled, chips=1, device_kind=kind)
+        model_s = max(report.compute_s, report.memory_s,
+                      report.collective_s)
+        entry["roofline"] = {
+            "dominant": report.dominant,
+            "intensity": round(report.flops
+                               / max(report.bytes_accessed, 1.0), 3),
+            "model_us": model_s * 1e6}
+    return {"key": cache_key(kind, B, N, d), "entry": entry}
 
 
 def main(argv=None) -> int:

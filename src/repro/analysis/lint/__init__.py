@@ -8,8 +8,7 @@ determinism, R003 the blessed packed-(3,B) host-view transfer, R004 the
 FaultSchedule statelessness discipline, R005 jit-tracing soundness, and
 R006 the Pallas-kernel / jnp-ref parity contract.
 
-Pure stdlib ``ast`` — no third-party dependencies beyond what the repo
-already ships (``tomli`` as the pre-3.11 ``tomllib`` fallback).
+Pure stdlib ``ast`` and ``tomllib`` — no third-party dependencies.
 """
 
 from .registry import REGISTRY, Finding, Rule, register

@@ -19,10 +19,7 @@ import dataclasses
 import os
 from typing import Dict, List, Optional, Tuple
 
-try:  # Python 3.11+
-    import tomllib as _toml
-except ImportError:  # this container: 3.10 + tomli
-    import tomli as _toml  # type: ignore[no-redef]
+import tomllib as _toml
 
 
 class LintConfigError(Exception):

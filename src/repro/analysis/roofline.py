@@ -8,11 +8,12 @@ Sources:
   collective-permute); per-op cost = sum of operand payload bytes.
 
 Terms (seconds, per device == per step for the whole machine under SPMD):
-    compute    = flops / PEAK_FLOPS
-    memory     = bytes_accessed / HBM_BW
-    collective = collective_bytes / ICI_BW_PER_LINK
+    compute    = flops / peak FLOP/s
+    memory     = bytes_accessed / HBM bytes/s
+    collective = collective_bytes / ICI bytes/s per link
 
-Hardware: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+The peaks come from :data:`PEAKS`, keyed by the ``device_kind`` JAX
+reports; a device that is not in the table is an error, not a default.
 """
 
 from __future__ import annotations
@@ -22,7 +23,35 @@ import re
 from collections import defaultdict
 from typing import Any, Dict, Optional
 
-from repro.launch.mesh import CHIP_HBM_BYTES, HBM_BW, ICI_BW_PER_LINK, PEAK_FLOPS_BF16
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+    flops_bf16: float         # FLOP/s
+    hbm_bw: float             # bytes/s
+    ici_bw_per_link: float    # bytes/s per link
+    hbm_bytes: float
+    source: str
+
+
+#: per-chip peaks keyed by ``jax.devices()[i].device_kind``
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12, hbm_bw=819e9, ici_bw_per_link=50e9,
+        hbm_bytes=16e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI over 4 links"),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """The peaks of a device kind; raises for a device not in the table."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (table: {sorted(PEAKS)})") from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -116,9 +145,10 @@ class RooflineReport:
         return dataclasses.asdict(self)
 
 
-def analyze_compiled(name: str, compiled, *, chips: int,
+def analyze_compiled(name: str, compiled, *, chips: int, device_kind: str,
                      model_flops: Optional[float] = None) -> RooflineReport:
-    """Roofline terms via the while-aware structural HLO model.
+    """Roofline terms via the while-aware structural HLO model, against the
+    peaks of ``device_kind``.
 
     ``compiled.cost_analysis()`` counts scan/while bodies once (verified —
     see analysis/hlo_cost.py), which undercounts layer-scanned models by the
@@ -128,11 +158,7 @@ def analyze_compiled(name: str, compiled, *, chips: int,
     structural = analyze_hlo(hlo)
     flops = float(structural["flops"])
     byts = float(structural["bytes"])
-    # cost_analysis() is a dict on newer jax, a per-computation list of
-    # dicts on older versions — normalize before reading
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     colls = {"bytes_by_op": structural["bytes_by_op"],
              "counts": structural["counts"],
              "total_bytes": int(structural["collective_bytes"]),
@@ -140,9 +166,10 @@ def analyze_compiled(name: str, compiled, *, chips: int,
              "xla_flops_once": float((ca or {}).get("flops", 0.0))}
     cbytes = float(structural["collective_bytes"])
 
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = byts / HBM_BW
-    coll_s = cbytes / ICI_BW_PER_LINK
+    chip = peaks(device_kind)
+    compute_s = flops / chip.flops_bf16
+    memory_s = byts / chip.hbm_bw
+    coll_s = cbytes / chip.ici_bw_per_link
     dom = max(("compute", compute_s), ("memory", memory_s),
               ("collective", coll_s), key=lambda kv: kv[1])[0]
 
@@ -151,7 +178,7 @@ def analyze_compiled(name: str, compiled, *, chips: int,
     tmp_b = getattr(mem, "temp_size_in_bytes", 0)
     out_b = getattr(mem, "output_size_in_bytes", 0)
     alias_b = getattr(mem, "alias_size_in_bytes", 0)
-    fits = (arg_b + tmp_b + out_b - alias_b) < CHIP_HBM_BYTES
+    fits = (arg_b + tmp_b + out_b - alias_b) < chip.hbm_bytes
 
     useful = None
     if model_flops:

@@ -247,7 +247,7 @@ def _svm_solve_batch(
 
     ``kernel`` (static) selects the solver's inner loop: ``True`` routes
     every λ stage through ``kernels.ops.pegasos_stage`` — the tiled Pallas
-    kernel on TPU, its dot-contraction jnp twin elsewhere — with the
+    kernel on TPU, its jnp twin elsewhere — with the
     first-0-error latch fused into the stage launch; ``False`` keeps the
     classic d-unrolled vmapped-XLA loop below, bit-identical to before
     this flag existed.  ``None`` (default) resolves to the backend: Pallas
@@ -269,7 +269,7 @@ def _svm_solve_batch(
     B, N, d = X.shape
     valid = y != 0.0
     nv = jnp.maximum(jnp.sum(valid, axis=1), 1).astype(X.dtype)  # (B,)
-    use_kernel = _kernel_ops._on_tpu() if kernel is None else bool(kernel)
+    use_kernel = _kernel_ops.on_tpu() if kernel is None else bool(kernel)
 
     # the d-contractions are spelled as broadcast multiply-adds: XLA:CPU
     # lowers the K=d (=2..10) dot through a generic GEMM path that is ~5×

@@ -104,10 +104,16 @@ def weighted_median_index(weights: np.ndarray) -> int:
 # Margins / separability (JAX data plane)
 # ---------------------------------------------------------------------------
 
+# f32 contractions on a TPU default to reduced-precision passes; the
+# protocols compare these projections strictly against bounds that are
+# themselves projections, so every contraction here asks for full f32
+_EXACT = jax.lax.Precision.HIGHEST
+
+
 @jax.jit
 def signed_margins(w: jnp.ndarray, b: jnp.ndarray, X: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     """y * (X @ w + b) — positive iff correctly classified."""
-    return y * (X @ w + b)
+    return y * (jnp.matmul(X, w, precision=_EXACT) + b)
 
 
 @jax.jit
@@ -136,7 +142,7 @@ def consistent_threshold_ranges(
     V: (m, 2) unit directions; Xw: (n, 2) transcript points; yw: (n,) ±1.
     Returns (lo, hi): each (m,).  With an empty transcript lo=-inf, hi=+inf.
     """
-    proj = V @ Xw.T  # (m, n)
+    proj = jnp.matmul(V, Xw.T, precision=_EXACT)  # (m, n)
     big = jnp.inf
     pos = yw == 1
     neg = yw == -1   # explicit: label-0 padding rows constrain neither side
@@ -165,7 +171,7 @@ def uncertain_mask(
     """
     lo, hi = consistent_threshold_ranges(V, Xw, yw)  # (m,)
     nonempty = (lo < hi) & dir_ok
-    proj = V @ X.T  # (m, n)
+    proj = jnp.matmul(V, X.T, precision=_EXACT)  # (m, n)
     pos_risk = proj > lo[:, None]
     neg_risk = proj < hi[:, None]
     at_risk = jnp.where((y == 1)[None, :], pos_risk, neg_risk)

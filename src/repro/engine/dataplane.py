@@ -16,23 +16,21 @@ pathologically slow).  Outputs are normalized to ±inf sentinels.
 Note these are the *bulk-scan* entry points — SOU diagnostics over final
 sweep state, and the rescan oracle that validates the engine's incremental
 ranges (tests/test_engine.py).  The engine's in-loop data plane is the fused
-inline pipeline in ``median.step`` plus append-time range maintenance; it
-does not route through this module (see DESIGN.md §data plane).
+inline pipeline in ``median.step`` plus append-time range maintenance; only
+with ``cut_kernel``/``extremes_kernel`` on does ``median.step`` route its
+two per-turn scans through :func:`median_cut` / :func:`median_extremes`
+here (see DESIGN.md §data plane).  Every ``use_pallas=None`` default is
+``kernels.ops.on_tpu()``.
 """
 
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
 from repro.kernels.support_margin import BIG
-
-
-def use_pallas_default() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def ranges(
@@ -69,7 +67,7 @@ def median_cut(
     for the whole sweep, never materializing the (B, m, n) risk tensor in
     HBM; elsewhere the jitted vmap reference.  Both produce identical
     integer scores (bit-for-bit, tested)."""
-    use_pallas = use_pallas_default() if use_pallas is None else use_pallas
+    use_pallas = ops.on_tpu() if use_pallas is None else use_pallas
     if use_pallas:
         return ops.support_median_cut_batch(
             V, dir_ok.astype(jnp.float32), lo, hi, X, y)
@@ -88,7 +86,7 @@ def median_extremes(
     ``kernels.support_margin.median_extremes_batched`` Pallas kernel, else
     the jitted vmap reference; identical integer row choices (bit-for-bit,
     tested in tests/test_kernels_interpret.py)."""
-    use_pallas = use_pallas_default() if use_pallas is None else use_pallas
+    use_pallas = ops.on_tpu() if use_pallas is None else use_pallas
     if use_pallas:
         return ops.support_extremes_batch(v, XW, yW)
     return ref.median_extremes_batch_ref(v, XW, yW)
@@ -105,7 +103,7 @@ def uncertain(
     use_pallas: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Batched SOU membership, bool (B, n); padding rows report False."""
-    use_pallas = use_pallas_default() if use_pallas is None else use_pallas
+    use_pallas = ops.on_tpu() if use_pallas is None else use_pallas
     if use_pallas:
         mask = ops.support_uncertain_batch(V, dir_ok, lo, hi, X, y)
     else:

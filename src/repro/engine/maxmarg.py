@@ -664,7 +664,7 @@ def run_instances(
     the Pallas kernel (default: on TPU only, like the MEDIAN selector's
     ``cut_kernel``); ``solver_kernel`` does the same for the refit solver
     itself — the tiled Pegasos stage kernel with its fused first-0-error
-    latch (jnp dot-contraction twin off-TPU; same TPU-only default).  ``mesh`` shards the hot path over a 1-D ("data",)
+    latch (jnp twin off-TPU; same TPU-only default).  ``mesh`` shards the hot path over a 1-D ("data",)
     device mesh (requires ``compact=True``); ``donate``/``overlap`` opt the
     per-turn dispatches into buffer donation and the double-buffered host
     loop (mesh default: both on).
@@ -678,7 +678,6 @@ def run_instances(
     """
     from repro.core import classifiers as clf
     from repro.core.protocols.one_way import ProtocolResult
-    from repro.engine import dataplane
 
     if mesh is not None and not compact:
         raise ValueError("sharded sweeps require the compacted hot path")
@@ -686,9 +685,9 @@ def run_instances(
         instances = [ProtocolInstance(inst.shards, eps, "maxmarg")
                      for inst in instances]
     if fused_kernel is None:
-        fused_kernel = dataplane.use_pallas_default()
+        fused_kernel = ops.on_tpu()
     if solver_kernel is None:
-        solver_kernel = dataplane.use_pallas_default()
+        solver_kernel = ops.on_tpu()
     data, state0, k, _cap = pack_instances_maxmarg(
         instances, max_epochs=max_epochs, max_support=max_support, mesh=mesh)
     if warm or compact:

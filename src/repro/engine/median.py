@@ -107,8 +107,9 @@ def _append2(wx, wy, fill, lo_j, hi_j, pts, labs, do, V):
     The node's consistent-threshold ranges (lo_j, hi_j) over its transcript
     are running max/mins, so they update incrementally here — O(B·m·2) per
     append instead of an O(B·m·cap) rescan per turn; masked/label-0 rows
-    contribute ∓inf, i.e. nothing.  ``repro.kernels`` ``threshold_ranges``
-    over the final buffer yields the identical values (tested).
+    contribute ∓inf, i.e. nothing.  ``repro.kernels``
+    ``threshold_ranges_batched`` over the final buffer yields the identical
+    values (tested).
     """
     labs = jnp.where(do[:, None], labs, 0).astype(jnp.int32)
     nvalid = jnp.sum(labs != 0, axis=1).astype(jnp.int32)
@@ -192,10 +193,11 @@ def step(
     (:mod:`repro.kernels.median_cut`) instead of the inline histogram
     pipeline — no (B, m, n) intermediate in HBM.  The kernel is bit-for-bit
     against its jnp reference (tested); against the *inline* path it can
-    pick a different — equally allowed — cut at FMA boundary ties, because
-    inline projections are broadcast multiply-adds while the kernel
-    contracts on the MXU (a shipped support point's own projection defines
-    the band edge its strict ``>`` risk test compares against).  Within a
+    pick a different — equally allowed — cut at FMA boundary ties: both
+    spell projections as the same multiply-adds, but a compiler may
+    contract them into FMAs in one program and not the other (a shipped
+    support point's own projection defines the band edge its strict ``>``
+    risk test compares against).  Within a
     backend the path is fixed, so B=1-vs-batch parity is unaffected.  The
     inline path stays the CPU default: XLA:CPU fuses it well and
     interpret-mode Pallas inside a hot loop is pathologically slow.
@@ -712,8 +714,8 @@ def run_instances(
     if eps is not None:
         instances = [ProtocolInstance(inst.shards, eps) for inst in instances]
     if cut_kernel is None or extremes_kernel is None:
-        from repro.engine import dataplane
-        tpu = dataplane.use_pallas_default()
+        from repro.kernels import ops
+        tpu = ops.on_tpu()
         cut_kernel = tpu if cut_kernel is None else cut_kernel
         extremes_kernel = tpu if extremes_kernel is None else extremes_kernel
     data, state0, k, _cap = pack_instances(

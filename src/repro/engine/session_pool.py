@@ -82,6 +82,7 @@ from repro.engine.state import (
     transcript_capacity,
     unified_transcript_capacity,
 )
+from repro.kernels import ops
 
 # host-side slot lifecycle (the device only ever sees done flags)
 SLOT_EMPTY = 0
@@ -430,8 +431,7 @@ class SessionPool:
         self.schedule = schedule if schedule is not None else F.FaultSchedule()
         self.stats: Dict[str, Any] = stats if stats is not None else {}
         # resolved once: the solver path is part of the pinned dispatch key
-        from repro.engine import dataplane
-        self._solver_kernel = (dataplane.use_pallas_default()
+        self._solver_kernel = (ops.on_tpu()
                                if config.solver_kernel is None
                                else bool(config.solver_kernel))
         W, k, n_pad, d = config.slots, config.k, config.n_pad, config.d
@@ -634,35 +634,37 @@ class SessionPool:
         key is appended to ``hotloop.KEY_LOG`` so the recompile gates
         cover pool traffic too."""
         cfg = self.cfg
-        W = cfg.slots
-        n_act = rows.size
-        n_pad = _round_up(W, hotloop.BATCH_MULT)
-        idx = np.full((n_pad,), W, np.int32)
-        idx[:n_act] = rows
-        width = cfg.cap
-        hotloop.KEY_LOG.append((n_pad, width, False, False))
-        if cfg.selector == "median":
-            self.state = median._hot_turn(
-                self.data, self._V, self.state, jnp.asarray(idx),
-                jnp.int32(n_act), k=cfg.k, first_turn=False,
-                cut_kernel=False, extremes_kernel=False, trans_width=width)
-        elif cfg.selector == "unified":
-            self.state = unified._hot_turn(
-                self.data, self._V, self.state, jnp.asarray(idx),
-                jnp.int32(n_act), k=cfg.k, max_support=cfg.max_support,
-                steps=cfg.svm_steps, stages=cfg.svm_stages, lam0=cfg.lam0,
-                trans_width=width, warm=False, per_node=False,
-                has_median=(cfg.d == 2), first_turn=False,
-                cut_kernel=False, extremes_kernel=False,
-                fused_kernel=False, solver_kernel=self._solver_kernel)
-        else:
-            self.state = maxmarg._hot_turn(
-                self.data, self.state, jnp.asarray(idx), jnp.int32(n_act),
-                k=cfg.k, max_support=cfg.max_support, steps=cfg.svm_steps,
-                stages=cfg.svm_stages, lam0=cfg.lam0, trans_width=width,
-                warm=False, per_node=False, fused_kernel=False,
-                solver_kernel=self._solver_kernel)
+        fn, args, kw = self.turn_call(rows)
+        hotloop.KEY_LOG.append(
+            (args[-2].shape[0], cfg.cap, False, False))
+        self.state = fn(*args, **kw)
         self.stats["dispatches"] += 1
+
+    def turn_call(self, rows: np.ndarray):
+        """The pinned dispatch of one pool turn over slot ``rows``, as
+        ``(jitted turn, operands, static keywords)``: ``fn(*args, **kw)``
+        is the turn ``_dispatch`` runs, and ``fn.lower(*args, **kw)`` is
+        the program every pool turn of this pool executes."""
+        cfg = self.cfg
+        W = cfg.slots
+        idx = np.full((_round_up(W, hotloop.BATCH_MULT),), W, np.int32)
+        idx[:rows.size] = rows
+        tail = (self.state, jnp.asarray(idx), jnp.int32(rows.size))
+        width = cfg.cap
+        if cfg.selector == "median":
+            return median._hot_turn, (self.data, self._V) + tail, dict(
+                k=cfg.k, first_turn=False, cut_kernel=False,
+                extremes_kernel=False, trans_width=width)
+        solver = dict(k=cfg.k, max_support=cfg.max_support,
+                      steps=cfg.svm_steps, stages=cfg.svm_stages,
+                      lam0=cfg.lam0, trans_width=width, warm=False,
+                      per_node=False, fused_kernel=False,
+                      solver_kernel=self._solver_kernel)
+        if cfg.selector == "unified":
+            return unified._hot_turn, (self.data, self._V) + tail, dict(
+                solver, has_median=(cfg.d == 2), first_turn=False,
+                cut_kernel=False, extremes_kernel=False)
+        return maxmarg._hot_turn, (self.data,) + tail, solver
 
     def _corrupt(self, rows: np.ndarray, kinds: np.ndarray):
         """Post-turn corruption wave at the pinned ``corrupt_block`` shape

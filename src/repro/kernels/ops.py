@@ -1,10 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-Each wrapper: (a) pads inputs to kernel tile boundaries, (b) dispatches to
-``interpret=True`` automatically off-TPU (this container is CPU-only; the
-kernel body then runs as a Python/XLA emulation, proving correctness while
-the BlockSpec tiling stays the TPU deployment artifact), (c) restores the
-caller's shapes.
+Each wrapper: (a) pads inputs to kernel tile boundaries, (b) compiles the
+kernel with Mosaic on a TPU and dispatches to ``interpret=True`` elsewhere
+(the kernel body then runs as a Python/XLA emulation, so the CPU tests
+check what the chip computes), (c) restores the caller's shapes.
 """
 
 from __future__ import annotations
@@ -25,7 +24,10 @@ from repro.kernels import support_margin as _sm
 from repro.analysis import autotune as _autotune
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
+    """The one backend probe behind every kernel-or-jnp default: Pallas
+    kernels run compiled on a TPU; elsewhere they interpret, and callers
+    that default a kernel path on take their jnp path instead."""
     return jax.default_backend() == "tpu"
 
 
@@ -57,7 +59,7 @@ def attention(
 ) -> jnp.ndarray:
     """Flash attention; pads Sq/Skv to block multiples (padding keys are
     masked out via ``kv_valid``)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = (not on_tpu()) if interpret is None else interpret
     B, Sq, H, hd = q.shape
     Skv = k.shape[1]
     bq = min(block_q, max(Sq, 8))
@@ -83,7 +85,7 @@ def rwkv6(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Chunked WKV; pads S to the chunk multiple (w=1, k=0 padding steps are
     state no-ops)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = (not on_tpu()) if interpret is None else interpret
     B, S, H, hd = r.shape
     chunk = min(chunk, S)
     pad = (-S) % chunk
@@ -107,7 +109,7 @@ def selective_scan(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Selective scan; pads S to the chunk multiple (Δ=0 steps are state
     no-ops) and d_inner to the block multiple."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = (not on_tpu()) if interpret is None else interpret
     B, S, di = xc.shape
     chunk = min(chunk, S)
     block_di = min(block_di, di)
@@ -124,26 +126,50 @@ def selective_scan(
 # ---------------------------------------------------------------------------
 # support margin (paper data plane)
 # ---------------------------------------------------------------------------
+#
+# The protocol kernels put points on the lane axis: shards are passed
+# transposed as (…, d, n) with (…, 1, n) labels, and point axes are padded
+# to the 128-lane tile (label-0 rows, inert under every masked reduction).
+# d is never padded — the projections are d multiply-adds.
 
-_LANE = 8  # contraction padding for the tiny-d protocol geometry
+_LANES = 128
+_SUBLANES = 8
+# points per median-cut tile are capped so the (m, block_n) risk planes
+# stay a few hundred KiB of VMEM at m = 1024 directions
+_CUT_TILE_ELEMS = 1 << 17
+
+
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def _tile(n: int, block: int, mult: int) -> int:
+    """Block size for an axis of length ``n``: at most ``block`` (rounded
+    up to the ``mult`` alignment), never more than ``n`` rounded up."""
+    return _round_up(min(block, n), mult)
+
+
+def _points_t(X: jnp.ndarray, y: jnp.ndarray, mult: int):
+    """(…, n, d) points + (…, n) labels -> lane-padded (…, d, n') f32
+    transposed points and (…, n') f32 labels."""
+    XT = _pad_to(jnp.swapaxes(X.astype(jnp.float32), -1, -2), X.ndim - 1,
+                 mult)
+    return XT, _pad_to(y.astype(jnp.float32), y.ndim - 1, mult)
+
+
+def _interp(interpret: Optional[bool]) -> bool:
+    return (not on_tpu()) if interpret is None else interpret
 
 
 def support_ranges(
     V: jnp.ndarray, Xw: jnp.ndarray, yw: jnp.ndarray, *,
     block_m: int = 256, block_n: int = 512, interpret: Optional[bool] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Consistent-threshold (lo, hi) per direction; pads m/n/d (padding
-    points get label 0 and are ignored by the masked reductions)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    m, n = V.shape[0], Xw.shape[0]
-    bm = min(block_m, max(m, 8))
-    bn = min(block_n, max(n, 8))
-    Vp = _pad_to(_pad_to(V, 0, bm), 1, _LANE)
-    Xp = _pad_to(_pad_to(Xw, 0, bn), 1, _LANE)
-    yp = _pad_to(yw.astype(jnp.float32), 0, bn)
-    lo, hi = _sm.threshold_ranges(Vp, Xp, yp, block_m=bm, block_n=bn,
-                                  interpret=interpret)
-    return lo[:m], hi[:m]
+    """Consistent-threshold (lo, hi) per direction for one transcript: the
+    batched kernel at B=1."""
+    lo, hi = support_ranges_batch(V, Xw[None], yw[None], block_m=block_m,
+                                  block_n=block_n, interpret=interpret)
+    return lo[0], hi[0]
 
 
 def support_uncertain(
@@ -151,20 +177,20 @@ def support_uncertain(
     X: jnp.ndarray, y: jnp.ndarray, *,
     block_m: int = 256, block_n: int = 512, interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
-    """SOU membership mask (bool, (n,)); pads m (dir_ok=0) and n."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    m, n = V.shape[0], X.shape[0]
-    bm = min(block_m, max(m, 8))
-    bn = min(block_n, max(n, 8))
-    Vp = _pad_to(_pad_to(V, 0, bm), 1, _LANE)
-    okp = _pad_to(dir_ok.astype(jnp.float32), 0, bm)
-    lop = _pad_to(lo, 0, bm)
-    hip = _pad_to(hi, 0, bm, value=-1.0)  # padded dirs: empty interval
-    Xp = _pad_to(_pad_to(X, 0, bn), 1, _LANE)
-    yp = _pad_to(y.astype(jnp.float32), 0, bn)
-    out = _sm.uncertain_mask(Vp, okp, lop, hip, Xp, yp, block_m=bm,
-                             block_n=bn, interpret=interpret)
-    return out[:n] > 0.5
+    """SOU membership mask (bool, (n,)) for one shard: the batched kernel
+    at B=1."""
+    return support_uncertain_batch(
+        V, dir_ok[None], lo[None], hi[None], X[None], y[None],
+        block_m=block_m, block_n=block_n, interpret=interpret)[0]
+
+
+def _direction_cols(m_pad: int, dir_ok, lo, hi):
+    """(B, m) direction masks/bounds -> (B, m_pad, 1) f32 columns; padded
+    directions are disallowed with an empty interval."""
+    okp = _pad_to(dir_ok.astype(jnp.float32), 1, m_pad)[..., None]
+    lop = _pad_to(lo.astype(jnp.float32), 1, m_pad)[..., None]
+    hip = _pad_to(hi.astype(jnp.float32), 1, m_pad, value=-1.0)[..., None]
+    return okp, lop, hip
 
 
 def support_ranges_batch(
@@ -173,17 +199,33 @@ def support_ranges_batch(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Batched consistent-threshold ranges: V (m, d) shared, Xw (B, n, d),
     yw (B, n) with label-0 padding rows.  One pallas_call over the whole
-    sweep; returns (B, m) lo/hi."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    sweep; returns (B, m) lo/hi (∓``BIG`` for an absent class)."""
     m, n = V.shape[0], Xw.shape[1]
-    bm = min(block_m, max(m, 8))
-    bn = min(block_n, max(n, 8))
-    Vp = _pad_to(_pad_to(V, 0, bm), 1, _LANE)
-    Xp = _pad_to(_pad_to(Xw, 1, bn), 2, _LANE)
-    yp = _pad_to(yw.astype(jnp.float32), 1, bn)
-    lo, hi = _sm.threshold_ranges_batched(Vp, Xp, yp, block_m=bm, block_n=bn,
-                                          interpret=interpret)
-    return lo[:, :m], hi[:, :m]
+    bm = _tile(m, block_m, _SUBLANES)
+    bn = _tile(n, block_n, _LANES)
+    XT, yp = _points_t(Xw, yw, bn)
+    lo, hi = _sm.threshold_ranges_batched(
+        _pad_to(V.astype(jnp.float32), 0, bm), XT, yp[:, None, :],
+        block_m=bm, block_n=bn, interpret=_interp(interpret))
+    return lo[:, :m, 0], hi[:, :m, 0]
+
+
+def support_uncertain_batch(
+    V: jnp.ndarray, dir_ok: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
+    X: jnp.ndarray, y: jnp.ndarray, *,
+    block_m: int = 256, block_n: int = 512, interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """Batched SOU membership: per-instance dir_ok/lo/hi (B, m) and shards
+    X (B, n, d) / y (B, n); returns bool (B, n)."""
+    m, n = V.shape[0], X.shape[1]
+    bm = _tile(m, block_m, _SUBLANES)
+    bn = _tile(n, block_n, _LANES)
+    okp, lop, hip = _direction_cols(_round_up(m, bm), dir_ok, lo, hi)
+    XT, yp = _points_t(X, y, bn)
+    out = _sm.uncertain_mask_batched(
+        _pad_to(V.astype(jnp.float32), 0, bm), okp, lop, hip, XT,
+        yp[:, None, :], block_m=bm, block_n=bn, interpret=_interp(interpret))
+    return out[:, 0, :n] > 0.5
 
 
 def support_median_cut_batch(
@@ -193,20 +235,26 @@ def support_median_cut_batch(
 ) -> jnp.ndarray:
     """Batched median-cut scores: per-instance dir_ok/lo/hi (B, m) and
     shards X (B, n, d) / y (B, n); returns int32 (B, m), -1 at disallowed
-    cuts.  Pads m (dir_ok=0 ⇒ score -1, sliced off), n (label-0 rows are
-    never live) and d."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    cuts.  Pads m (dir_ok=0 ⇒ score -1, sliced off) and n (label-0 rows are
+    never live)."""
     m, n = V.shape[0], X.shape[1]
-    bn = min(block_n, max(n, 8))
-    Vp = _pad_to(_pad_to(V, 0, 8), 1, _LANE)
-    okp = _pad_to(dir_ok.astype(jnp.float32), 1, 8)
-    lop = _pad_to(lo, 1, 8)
-    hip = _pad_to(hi, 1, 8, value=-1.0)  # padded dirs: empty interval
-    Xp = _pad_to(_pad_to(X, 1, bn), 2, _LANE)
-    yp = _pad_to(y.astype(jnp.float32), 1, bn)
-    out = _mc.median_cut_scores_batched(Vp, okp, lop, hip, Xp, yp,
-                                        block_n=bn, interpret=interpret)
-    return out[:, :m]
+    mp = _round_up(m, _SUBLANES)
+    cap = max(_LANES, _CUT_TILE_ELEMS // mp // _LANES * _LANES)
+    bn = _tile(n, min(block_n, cap), _LANES)
+    okp, lop, hip = _direction_cols(mp, dir_ok, lo, hi)
+    XT, yp = _points_t(X, y, bn)
+    out = _mc.median_cut_scores_batched(
+        _pad_to(V.astype(jnp.float32), 0, mp), okp, lop, hip, XT,
+        yp[:, None, :], block_n=bn, interpret=_interp(interpret))
+    return out[:, :m, 0]
+
+
+def _node_cols(w: jnp.ndarray, k: int) -> jnp.ndarray:
+    """(B, d) per-instance vectors -> (B, d, k, 1) f32, each coordinate
+    repeated down the k node rows the kernel scales."""
+    B, d = w.shape
+    return jnp.broadcast_to(w.astype(jnp.float32)[:, :, None, None],
+                            (B, d, k, 1))
 
 
 def support_violation_batch(
@@ -216,27 +264,26 @@ def support_violation_batch(
     interpret: Optional[bool] = None,
 ):
     """Fused MAXMARG turn scan (support band ranks + per-node error counts +
-    most-violated ranks) for a whole sweep; pads N/n/d (label-0 rows are
+    most-violated ranks) for a whole sweep; pads N/n (label-0 rows are
     never band members, never valid, never miscounted) and restores the
     reference's rank sentinels (N for non-band fit rows, n for invalid shard
     rows) after slicing the padding off.  Returns
     ``(sup_rank (B, N) i32, err_k (B, k) i32, viol_rank (B, k, n) i32)`` —
     bit-for-bit ``ref.maxmarg_turn_batch_ref``."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    N, n = K.shape[1], X.shape[2]
-    Kp = _pad_to(_pad_to(K, 1, 8), 2, _LANE)
-    yKp = _pad_to(yK.astype(jnp.float32), 1, 8)
-    Xp = _pad_to(_pad_to(X, 2, 8), 3, _LANE)
-    yp = _pad_to(y.astype(jnp.float32), 2, 8)
-    wp = _pad_to(w, 1, _LANE)
+    N, k, n = K.shape[1], X.shape[1], X.shape[2]
+    KT, yKp = _points_t(K, yK, _LANES)
+    XT, yp = _points_t(X, y, _LANES)
     sup, err, viol = _sm.maxmarg_turn_scan_batched(
-        wp, b, Kp, yKp, Xp, yp, rtol=rtol, max_support=max_support,
-        viol_ship=viol_ship, interpret=interpret)
+        _node_cols(w, k),
+        b.astype(jnp.float32)[:, None, None],
+        KT[:, :, None, :], yKp[:, None, :],
+        jnp.swapaxes(XT, 1, 2), yp, rtol=rtol, max_support=max_support,
+        viol_ship=viol_ship, interpret=_interp(interpret))
     # padded widths inflate the non-member sentinel; members rank < N (resp.
     # n), so a min against the true width restores the reference sentinel
-    sup = jnp.minimum(sup[:, :N], N)
+    sup = jnp.minimum(sup[:, 0, :N], N)
     viol = jnp.minimum(viol[:, :, :n], n)
-    return sup, err, viol
+    return sup, err[..., 0], viol
 
 
 def support_extremes_batch(
@@ -247,37 +294,15 @@ def support_extremes_batch(
     the proposed direction) for a whole sweep: v (B, d), XW (B, k, nW, d),
     yW (B, k, nW) with label-0 padding rows.  ``nW`` is *fill-capped* — the
     hot loop passes transcripts sliced to the live width, and this wrapper
-    only re-pads to tile boundaries (padding rows get label 0 and are never
+    only re-pads to the lane tile (padding rows get label 0 and are never
     selected; a class with no members yields index 0, gated by the caller's
     presence flags).  Returns ``(i_p, i_q)`` each (B, k) i32, bit-for-bit
     ``ref.median_extremes_batch_ref``."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    vp = _pad_to(v, 1, _LANE)
-    Xp = _pad_to(_pad_to(XW, 2, 8), 3, _LANE)
-    yp = _pad_to(yW.astype(jnp.float32), 2, 8)
-    return _sm.median_extremes_batched(vp, Xp, yp, interpret=interpret)
-
-
-def support_uncertain_batch(
-    V: jnp.ndarray, dir_ok: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
-    X: jnp.ndarray, y: jnp.ndarray, *,
-    block_m: int = 256, block_n: int = 512, interpret: Optional[bool] = None,
-) -> jnp.ndarray:
-    """Batched SOU membership: per-instance dir_ok/lo/hi (B, m) and shards
-    X (B, n, d) / y (B, n); returns bool (B, n)."""
-    interpret = (not _on_tpu()) if interpret is None else interpret
-    m, n = V.shape[0], X.shape[1]
-    bm = min(block_m, max(m, 8))
-    bn = min(block_n, max(n, 8))
-    Vp = _pad_to(_pad_to(V, 0, bm), 1, _LANE)
-    okp = _pad_to(dir_ok.astype(jnp.float32), 1, bm)
-    lop = _pad_to(lo, 1, bm)
-    hip = _pad_to(hi, 1, bm, value=-1.0)  # padded dirs: empty interval
-    Xp = _pad_to(_pad_to(X, 1, bn), 2, _LANE)
-    yp = _pad_to(y.astype(jnp.float32), 1, bn)
-    out = _sm.uncertain_mask_batched(Vp, okp, lop, hip, Xp, yp, block_m=bm,
-                                     block_n=bn, interpret=interpret)
-    return out[:, :n] > 0.5
+    XT, yp = _points_t(XW, yW, _LANES)
+    ip, iq = _sm.median_extremes_batched(
+        _node_cols(v, XW.shape[1]), jnp.swapaxes(XT, 1, 2), yp,
+        interpret=_interp(interpret))
+    return ip[..., 0], iq[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -307,15 +332,15 @@ def pegasos_stage(
 
     The solver's single dispatch point (``_svm_solve_batch(kernel=True)``):
     Pallas tiled kernel on TPU (auto-interpret elsewhere, like every other
-    wrapper here), dot-contraction jnp twin (``ref.pegasos_stage_batch_ref``)
-    when ``use_pallas`` resolves False — the CPU fast path for d ≫ 2.
-    Block shapes / unroll default from the committed autotune cache
-    (``analysis.autotune.lookup_tile``) with its deterministic fallback.
-    Returns ``(w, b, mmin, found, w_best, b_best)``; ``mmin`` follows the
-    kernel mask convention (``pegasos.BIG`` where no valid rows).
+    wrapper here), its jnp twin (``ref.pegasos_stage_batch_ref``) when
+    ``use_pallas`` resolves False.  Block shapes / unroll default from the
+    committed autotune cache (``analysis.autotune.lookup_tile``) with its
+    deterministic fallback.  Returns ``(w, b, mmin, found, w_best,
+    b_best)``; ``mmin`` follows the kernel mask convention (``pegasos.BIG``
+    where no valid rows).
     """
     B, N, d = X.shape
-    use_pallas = _on_tpu() if use_pallas is None else use_pallas
+    use_pallas = on_tpu() if use_pallas is None else use_pallas
     if block_b is None or block_n is None or unroll is None:
         kind = jax.devices()[0].device_kind
         cfg = _autotune.lookup_tile(kind, B, N, d)
@@ -328,23 +353,24 @@ def pegasos_stage(
             X, y, nv, w, b, lam, found, w_best, b_best,
             nsteps=nsteps, t0=t0, unroll=unroll)
 
-    interpret = (not _on_tpu()) if interpret is None else interpret
     bb = min(block_b, max(B, 1))
-    bn = min(block_n, max(N, 8))
-    # pads are inert by construction: label-0 rows never violate, zero d
-    # columns stay zero through the update, pad instances get nv=1 / λ=1
-    Xp = _pad_to(_pad_to(_pad_to(X, 0, bb), 1, bn), 2, _LANE)
-    yp = _pad_to(y.astype(jnp.float32), 0, bb)
-    yp = _pad_to(yp, 1, bn)
-    nvp = _pad_to(nv.astype(jnp.float32), 0, bb, value=1.0)
-    lamp = _pad_to(lam.astype(jnp.float32), 0, bb, value=1.0)
-    wp = _pad_to(_pad_to(w.astype(jnp.float32), 0, bb), 1, _LANE)
-    bp = _pad_to(b.astype(jnp.float32), 0, bb)
-    fp = _pad_to(found.astype(jnp.int32), 0, bb)
-    wbp = _pad_to(_pad_to(w_best.astype(jnp.float32), 0, bb), 1, _LANE)
-    bbp = _pad_to(b_best.astype(jnp.float32), 0, bb)
+    bn = _tile(N, block_n, _LANES)
+    f32 = jnp.float32
+
+    def one(a, value=0.0):         # (B,) -> block-padded (B', 1, 1)
+        return _pad_to(a.astype(f32), 0, bb, value=value)[:, None, None]
+
+    def col(a):                    # (B, d) -> block-padded (B', d, 1)
+        return _pad_to(a.astype(f32), 0, bb)[..., None]
+
+    # pads are inert by construction: label-0 rows never violate, pad
+    # instances get nv=1 / λ=1 and no valid rows
+    XT, yp = _points_t(X, y, bn)
     w_o, b_o, mm_o, f_o, wb_o, bb_o = _pg.pegasos_stage_batched(
-        Xp, yp, nvp, wp, bp, lamp, fp, wbp, bbp, nsteps=nsteps, t0=t0,
-        block_b=bb, block_n=bn, interpret=interpret)
-    return (w_o[:B, :d], b_o[:B], mm_o[:B], f_o[:B] != 0,
-            wb_o[:B, :d], bb_o[:B])
+        _pad_to(XT, 0, bb), _pad_to(yp, 0, bb)[:, None, :],
+        one(nv, 1.0), col(w), one(b), one(lam, 1.0),
+        _pad_to(found.astype(jnp.int32), 0, bb)[:, None, None],
+        col(w_best), one(b_best), nsteps=nsteps, t0=t0,
+        block_b=bb, block_n=bn, interpret=_interp(interpret))
+    return (w_o[:B, :, 0], b_o[:B, 0, 0], mm_o[:B, 0, 0],
+            f_o[:B, 0, 0] != 0, wb_o[:B, :, 0], bb_o[:B, 0, 0])

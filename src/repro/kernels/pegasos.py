@@ -2,16 +2,20 @@
 
 ``core.classifiers._svm_solve_batch`` runs every hard-margin refit as plain
 vmapped XLA Pegasos over ``(B, N, d)``: one ``fori_loop`` step per gradient
-pass, with the d-contraction spelled as d broadcast multiply-adds (the fast
-form at the paper's d = 2..10, but *solver-bound* at d ≫ 2 — ROADMAP's TPU
-kernel item).  This kernel is the tiled deployment artifact for that loop:
+pass, with the d-contraction spelled as d broadcast multiply-adds.  This
+kernel is the tiled deployment artifact for that loop:
 
 * grid ``(B/block_b, nsteps+1, N/block_n)`` — instances in parallel blocks,
   the Pegasos step axis sequential, N-tiles innermost;
-* the hinge-gradient reduction is accumulated across N-tiles in an f32 VMEM
-  scratch (``g_s``/``gb_s``); ``d`` stays fully resident per block, so each
-  step's two contractions (margins ``X·w``, gradient ``violᵀ·X``) are real
-  MXU matmuls instead of d strided passes;
+* points sit on the lane axis: the fit set comes transposed as (B, d, N)
+  with labels (B, 1, N), and every per-instance vector is a (d, 1) or
+  (1, 1) column of a (B, d, 1) / (B, 1, 1) operand, so each block's
+  trailing two dims are whole or (8, 128)-aligned;
+* each step's two contractions are spelled over the small d: margins as d
+  multiply-adds in coordinate order (the arithmetic of the classic solver
+  and of the ``ref.pegasos_stage_batch_ref`` twin), the hinge gradient as a
+  multiply and a lane reduction per coordinate, accumulated across N-tiles
+  in an f32 VMEM scratch (``g_s``/``gb_s``);
 * the separator itself lives in VMEM scratch across the whole stage — one
   kernel launch covers a *whole λ stage* (nsteps updates + the trailing
   margin scan), not one ``fori_loop`` step per dispatch;
@@ -25,8 +29,8 @@ kernel item).  This kernel is the tiled deployment artifact for that loop:
 
 Block shapes come from the committed tuning cache
 (``kernels/tuning_cache.json`` via ``analysis.autotune.lookup_tile``); the
-``ops.pegasos_stage`` wrapper pads/dispatches and falls back to the
-dot-contraction jnp twin (``ref.pegasos_stage_batch_ref``) off-TPU.
+``ops.pegasos_stage`` wrapper pads/dispatches and falls back to the jnp
+twin off-TPU.
 """
 
 from __future__ import annotations
@@ -38,7 +42,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-BIG = 1e30  # mask constant; jnp.inf is avoided inside kernels (see support_margin)
+BIG = 1e30  # mask constant for the min-margin scan of an all-padding block
+
+
+def decide(XT: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """(…, d, n) transposed points × (…, d, 1) separators + (…, 1, 1)
+    offsets -> (…, 1, n) decision values, as d multiply-adds in coordinate
+    order: exact f32 on every backend, and the arithmetic of the jnp twin
+    ``ref.pegasos_stage_batch_ref``."""
+    acc = XT[..., 0:1, :] * w[..., 0:1, :]
+    for j in range(1, XT.shape[-2]):
+        acc = acc + XT[..., j:j + 1, :] * w[..., j:j + 1, :]
+    return acc + b
 
 
 def _pegasos_stage_kernel(
@@ -61,8 +76,8 @@ def _pegasos_stage_kernel(
 
     @pl.when((s == 0) & (ni == 0))
     def _load():
-        w_s[...] = w0_ref[...].astype(jnp.float32)
-        b_s[...] = b0_ref[...].astype(jnp.float32)
+        w_s[...] = w0_ref[...]
+        b_s[...] = b0_ref[...]
 
     @pl.when(ni == 0)
     def _zero():
@@ -70,40 +85,34 @@ def _pegasos_stage_kernel(
         gb_s[...] = jnp.zeros_like(gb_s)
         mm_s[...] = jnp.full_like(mm_s, BIG)
 
-    X = x_ref[...].astype(jnp.float32)                   # (bb, bn, d)
-    yv = y_ref[...].astype(jnp.float32)                  # (bb, bn)
+    X = x_ref[...]                                       # (bb, d, bn)
+    yv = y_ref[...]                                      # (bb, 1, bn)
     valid = yv != 0.0
-    w = w_s[...]                                         # (bb, d)
-    # margins of the current iterate on this tile — MXU batched matvec
-    m = yv * (jnp.einsum("bnd,bd->bn", X, w,
-                         preferred_element_type=jnp.float32)
-              + b_s[...][:, None])
+    m = yv * decide(X, w_s[...], b_s[...])               # (bb, 1, bn)
 
     @pl.when(s < nsteps)
     def _grad():
-        viol = ((m < 1.0) & valid).astype(jnp.float32)
-        vy = viol * yv
-        g_s[...] += jnp.einsum("bn,bnd->bd", vy, X,
-                               preferred_element_type=jnp.float32)
-        gb_s[...] += jnp.sum(vy, axis=1)
+        vy = ((m < 1.0) & valid).astype(jnp.float32) * yv
+        g_s[...] += jnp.sum(vy * X, axis=2, keepdims=True)
+        gb_s[...] += jnp.sum(vy, axis=2, keepdims=True)
 
     @pl.when(s == nsteps)
     def _margin():
-        mm_s[...] = jnp.minimum(mm_s[...],
-                                jnp.min(jnp.where(valid, m, BIG), axis=1))
+        mm_s[...] = jnp.minimum(mm_s[...], jnp.min(
+            jnp.where(valid, m, BIG), axis=2, keepdims=True))
 
     @pl.when((s < nsteps) & (ni == num_n_blocks - 1))
     def _update():
-        lam = lam_ref[...].astype(jnp.float32)           # (bb,)
-        nv = nv_ref[...].astype(jnp.float32)
+        lam = lam_ref[...]                               # (bb, 1, 1)
+        nv = nv_ref[...]
         eta = 1.0 / (lam * (s.astype(jnp.float32) + 2.0 + t0))
-        gw = lam[:, None] * w_s[...] - g_s[...] / nv[:, None]
+        gw = lam * w_s[...] - g_s[...] / nv
         gb = -gb_s[...] / nv
-        w2 = w_s[...] - eta[:, None] * gw
+        w2 = w_s[...] - eta * gw
         b2 = b_s[...] - eta * gb
-        nrm = jnp.sqrt(jnp.sum(w2 * w2, axis=1))
+        nrm = jnp.sqrt(jnp.sum(w2 * w2, axis=1, keepdims=True))
         scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / (nrm + 1e-12))
-        w_s[...] = w2 * scale[:, None]
+        w_s[...] = w2 * scale
         b_s[...] = b2 * scale
 
     @pl.when((s == nsteps) & (ni == num_n_blocks - 1))
@@ -116,24 +125,22 @@ def _pegasos_stage_kernel(
         b_out[...] = b_s[...]
         mmin_out[...] = mm
         found_out[...] = (found_in | ok).astype(jnp.int32)
-        wbest_out[...] = jnp.where(take[:, None], w_s[...],
-                                   wb_ref[...].astype(jnp.float32))
-        bbest_out[...] = jnp.where(take, b_s[...],
-                                   bb_ref[...].astype(jnp.float32))
+        wbest_out[...] = jnp.where(take, w_s[...], wb_ref[...])
+        bbest_out[...] = jnp.where(take, b_s[...], bb_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("nsteps", "t0", "block_b",
                                              "block_n", "interpret"))
 def pegasos_stage_batched(
-    X: jnp.ndarray,                # (B, N, d) f32; label-0 rows are padding
-    y: jnp.ndarray,                # (B, N) f32 in {+1, -1, 0}
-    nv: jnp.ndarray,               # (B,) f32 — per-instance valid row count
-    w: jnp.ndarray,                # (B, d) stage-entry separator
-    b: jnp.ndarray,                # (B,)
-    lam: jnp.ndarray,              # (B,) per-instance stage λ
-    found: jnp.ndarray,            # (B,) i32 — latch state in
-    w_best: jnp.ndarray,           # (B, d) latched separator in
-    b_best: jnp.ndarray,           # (B,)
+    XT: jnp.ndarray,               # (B, d, N) f32 fit sets, transposed
+    y: jnp.ndarray,                # (B, 1, N) f32 in {+1, -1, 0}; 0 = padding
+    nv: jnp.ndarray,               # (B, 1, 1) f32 — per-instance valid count
+    w: jnp.ndarray,                # (B, d, 1) stage-entry separator
+    b: jnp.ndarray,                # (B, 1, 1)
+    lam: jnp.ndarray,              # (B, 1, 1) per-instance stage λ
+    found: jnp.ndarray,            # (B, 1, 1) i32 — latch state in
+    w_best: jnp.ndarray,           # (B, d, 1) latched separator in
+    b_best: jnp.ndarray,           # (B, 1, 1)
     *,
     nsteps: int,
     t0: float = 0.0,
@@ -144,12 +151,13 @@ def pegasos_stage_batched(
     """One fused Pegasos λ stage + first-0-error latch as one pallas_call.
 
     Shapes must tile evenly (the ``ops.pegasos_stage`` wrapper pads).
-    Returns ``(w, b, mmin, found, w_best, b_best)``; ``mmin`` uses the
-    kernel mask constant ``BIG`` (not inf) for instances with no valid
-    rows — callers that need the inf convention recompute margins
-    themselves (``_svm_solve_batch`` does, for canonicalization only).
+    Returns ``(w, b, mmin, found, w_best, b_best)`` in the operand layouts;
+    ``mmin`` uses the kernel mask constant ``BIG`` (not inf) for instances
+    with no valid rows — callers that need the inf convention recompute
+    margins themselves (``_svm_solve_batch`` does, for canonicalization
+    only).
     """
-    B, N, d = X.shape
+    B, d, N = XT.shape
     block_b = min(block_b, B)
     block_n = min(block_n, N)
     assert B % block_b == 0 and N % block_n == 0, (B, block_b, N, block_n)
@@ -157,33 +165,34 @@ def pegasos_stage_batched(
 
     kernel = functools.partial(_pegasos_stage_kernel, nsteps=nsteps,
                                num_n_blocks=nn, t0=t0)
-    vec = pl.BlockSpec((block_b,), lambda bi, s, ni: (bi,))
-    mat = pl.BlockSpec((block_b, d), lambda bi, s, ni: (bi, 0))
-    w_o, b_o, mm_o, f_o, wb_o, bb_o = pl.pallas_call(
+    one = pl.BlockSpec((block_b, 1, 1), lambda bi, s, ni: (bi, 0, 0))
+    col = pl.BlockSpec((block_b, d, 1), lambda bi, s, ni: (bi, 0, 0))
+    f32 = jnp.float32
+    return pl.pallas_call(
         kernel,
         grid=(nb, nsteps + 1, nn),
         in_specs=[
-            pl.BlockSpec((block_b, block_n, d),
-                         lambda bi, s, ni: (bi, ni, 0)),
-            pl.BlockSpec((block_b, block_n), lambda bi, s, ni: (bi, ni)),
-            vec, mat, vec, vec, vec, mat, vec,
+            pl.BlockSpec((block_b, d, block_n),
+                         lambda bi, s, ni: (bi, 0, ni)),
+            pl.BlockSpec((block_b, 1, block_n),
+                         lambda bi, s, ni: (bi, 0, ni)),
+            one, col, one, one, one, col, one,
         ],
-        out_specs=[mat, vec, vec, vec, mat, vec],
+        out_specs=[col, one, one, one, col, one],
         out_shape=[
-            jax.ShapeDtypeStruct((B, d), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-            jax.ShapeDtypeStruct((B, d), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
+            jax.ShapeDtypeStruct((B, d, 1), f32),
+            jax.ShapeDtypeStruct((B, 1, 1), f32),
+            jax.ShapeDtypeStruct((B, 1, 1), f32),
+            jax.ShapeDtypeStruct((B, 1, 1), jnp.int32),
+            jax.ShapeDtypeStruct((B, d, 1), f32),
+            jax.ShapeDtypeStruct((B, 1, 1), f32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_b, d), jnp.float32),       # w iterate
-            pltpu.VMEM((block_b,), jnp.float32),         # b iterate
-            pltpu.VMEM((block_b, d), jnp.float32),       # hinge-gradient acc
-            pltpu.VMEM((block_b,), jnp.float32),         # offset-gradient acc
-            pltpu.VMEM((block_b,), jnp.float32),         # running min margin
+            pltpu.VMEM((block_b, d, 1), f32),            # w iterate
+            pltpu.VMEM((block_b, 1, 1), f32),            # b iterate
+            pltpu.VMEM((block_b, d, 1), f32),            # hinge-gradient acc
+            pltpu.VMEM((block_b, 1, 1), f32),            # offset-gradient acc
+            pltpu.VMEM((block_b, 1, 1), f32),            # running min margin
         ],
         interpret=interpret,
-    )(X, y, nv, w, b, lam, found, w_best, b_best)
-    return w_o, b_o, mm_o, f_o, wb_o, bb_o
+    )(XT, y, nv, w, b, lam, found, w_best, b_best)

@@ -3,6 +3,13 @@
 Each function is the direct, unblocked mathematical definition — no tiling,
 no online rescaling — used by the per-kernel ``assert_allclose`` sweeps in
 ``tests/test_kernels.py``.
+
+The protocol oracles spell every projection as d multiply-adds in
+coordinate order (``_proj`` / ``_dot``), never as a ``@`` contraction: that
+is the arithmetic of the Pallas kernels and of the engine's inline scans,
+it is exact f32 on every backend (a TPU ``@`` on f32 runs at reduced
+precision by default), and strict comparisons against bounds that are
+themselves projections then agree bit-for-bit.
 """
 
 from __future__ import annotations
@@ -105,6 +112,22 @@ def mamba_ref(
     return ys.transpose(1, 0, 2).astype(xc.dtype), hT
 
 
+def _proj(V: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
+    """(m, d) directions × (n, d) points -> (m, n) projections."""
+    acc = V[:, 0, None] * X[None, :, 0]
+    for j in range(1, V.shape[1]):
+        acc = acc + V[:, j, None] * X[None, :, j]
+    return acc
+
+
+def _dot(X: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """(..., d) points × (d,) direction -> (...) projections."""
+    acc = X[..., 0] * w[0]
+    for j in range(1, w.shape[0]):
+        acc = acc + X[..., j] * w[j]
+    return acc
+
+
 def threshold_ranges_ref(
     V: jnp.ndarray,                # (m, d) directions
     Xw: jnp.ndarray,               # (n, d) transcript points
@@ -116,7 +139,7 @@ def threshold_ranges_ref(
     predict +1 iff v·x < t, so lo = max over positives, hi = min over
     negatives.  Label-0 rows (the padding convention) constrain neither side.
     """
-    proj = V @ Xw.T
+    proj = _proj(V, Xw)
     big = jnp.inf
     pos = yw == 1
     neg = yw == -1
@@ -135,7 +158,7 @@ def uncertain_mask_ref(
 ) -> jnp.ndarray:
     """Set-of-uncertainty membership for each point of (X, y)."""
     nonempty = (lo < hi) & dir_ok
-    proj = V @ X.T                 # (m, n)
+    proj = _proj(V, X)             # (m, n)
     pos_risk = proj > lo[:, None]
     neg_risk = proj < hi[:, None]
     at_risk = jnp.where((y == 1)[None, :], pos_risk, neg_risk)
@@ -159,7 +182,7 @@ def median_cut_scores_ref(
     (``kernels.median_cut.median_cut_scores_batched``) matches bit-for-bit.
     """
     m = V.shape[0]
-    proj = V @ X.T                                      # (m, n)
+    proj = _proj(V, X)                                  # (m, n)
     nonempty = (lo < hi) & dir_ok
     lo_r = jnp.where(nonempty, lo, jnp.inf)
     hi_r = jnp.where(nonempty, hi, -jnp.inf)
@@ -190,7 +213,7 @@ def median_extremes_ref(
     the live width, not the static capacity — any ``nW`` is valid under the
     label-0 padding convention.
     """
-    pj = XW @ v                                          # (k, nW)
+    pj = _dot(XW, v)                                     # (k, nW)
     i_p = jnp.argmax(jnp.where(yW == 1, pj, -jnp.inf), axis=1)
     i_q = jnp.argmin(jnp.where(yW == -1, pj, jnp.inf), axis=1)
     return i_p.astype(jnp.int32), i_q.astype(jnp.int32)
@@ -248,12 +271,12 @@ def maxmarg_turn_ref(
       loop's ``argsort(m)[:2]`` wire order.
     """
     valid_K = yK != 0
-    mK = yK.astype(K.dtype) * (K @ w + b)
+    mK = yK.astype(K.dtype) * (_dot(K, w) + b)
     mmin = jnp.maximum(jnp.min(jnp.where(valid_K, mK, jnp.inf)), 1e-12)
     band = valid_K & (mK <= mmin * (1.0 + rtol))
     sup_rank = _topr_ranks(mK, band, max_support)
 
-    dec = X @ w + b                                      # (k, n)
+    dec = _dot(X, w) + b                                 # (k, n)
     pred = jnp.where(dec > 0, 1, -1)
     valid = y != 0
     err_k = jnp.sum((pred != y) & valid, axis=1).astype(jnp.int32)
@@ -310,23 +333,28 @@ def pegasos_stage_batch_ref(
     ``kernels.pegasos.pegasos_stage_batched`` and the solver's CPU fast
     path (``_svm_solve_batch(kernel=True)`` off-TPU).
 
-    Same op sequence as the kernel body — einsum d-contractions (real
-    GEMMs, unlike the classic solver's per-d broadcast unroll, which is
-    what makes this the d ≫ 2 fast path even on CPU), hinge gradient
-    normalized by ``nv``, L2-ball projection, and a trailing min-margin
-    scan folded into the latch.  ``mmin`` uses the kernel mask constant
-    ``pegasos.BIG`` (not inf) for instances with no valid rows.  ``unroll``
-    is the CPU tuning knob from the autotune cache; it never changes the
-    math, only the fori_loop unrolling.
+    Same op sequence as the kernel body — margins as d multiply-adds in
+    coordinate order, the hinge gradient as one multiply and a point-axis
+    sum per coordinate, normalized by ``nv``, L2-ball projection, and a
+    trailing min-margin scan folded into the latch.  ``mmin`` uses the
+    kernel mask constant ``pegasos.BIG`` (not inf) for instances with no
+    valid rows.  ``unroll`` is the CPU tuning knob from the autotune cache;
+    it never changes the math, only the fori_loop unrolling.
     """
     big = 1e30
     valid = y != 0.0
 
+    def decide(wi, bi):
+        acc = X[:, :, 0] * wi[:, None, 0]
+        for j in range(1, X.shape[2]):
+            acc = acc + X[:, :, j] * wi[:, None, j]
+        return acc + bi[:, None]
+
     def step(i, carry):
         wi, bi = carry
-        m = y * (jnp.einsum("bnd,bd->bn", X, wi) + bi[:, None])
+        m = y * decide(wi, bi)
         vy = ((m < 1.0) & valid).astype(X.dtype) * y
-        g = jnp.einsum("bn,bnd->bd", vy, X)
+        g = jnp.sum(vy[:, :, None] * X, axis=1)
         gb = -jnp.sum(vy, axis=1) / nv
         eta = 1.0 / (lam * (i.astype(X.dtype) + 2.0 + t0))
         w2 = wi - eta[:, None] * (lam[:, None] * wi - g / nv[:, None])
@@ -336,8 +364,7 @@ def pegasos_stage_batch_ref(
         return w2 * scale[:, None], b2 * scale
 
     w, b = jax.lax.fori_loop(0, nsteps, step, (w, b), unroll=unroll)
-    m = y * (jnp.einsum("bnd,bd->bn", X, w,
-                        preferred_element_type=jnp.float32) + b[:, None])
+    m = y * decide(w, b)
     mmin = jnp.min(jnp.where(valid, m, big), axis=1)
     ok = mmin > 0.0
     take = ok & ~found

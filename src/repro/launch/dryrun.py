@@ -187,7 +187,7 @@ def run_case(arch: str, shape_name: str, mesh_kind: str,
         compiled = lowered.compile()
         t2 = time.time()
         rep = analyze_compiled(f"{arch}/{shape_name}/{mesh_kind}", compiled,
-                               chips=chips,
+                               chips=chips, device_kind="TPU v5 lite",
                                model_flops=model_flops_estimate(cfg, shape))
         rec.update(status="ok", lower_s=round(t1 - t0, 2),
                    compile_s=round(t2 - t1, 2), roofline=rep.as_dict())

@@ -21,11 +21,8 @@ import jax
 
 
 def _axis_kw(n_axes: int) -> dict:
-    """``axis_types`` kwarg when this jax version has explicit axis kinds
-    (0.5+); older versions (0.4.x) have Auto-only meshes and no AxisType."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+    """Every mesh axis is an Auto (compiler-sharded) axis."""
+    return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -55,10 +52,3 @@ def make_data_mesh(n_devices: Optional[int] = None) -> jax.sharding.Mesh:
     if not 1 <= n <= avail:
         raise ValueError(f"need 1 <= n_devices <= {avail}, got {n}")
     return jax.make_mesh((n,), ("data",), **_axis_kw(1))
-
-
-# TPU v5e hardware constants (per chip) used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12      # FLOP/s
-HBM_BW = 819e9                # bytes/s
-ICI_BW_PER_LINK = 50e9        # bytes/s/link
-CHIP_HBM_BYTES = 16e9         # 16 GB

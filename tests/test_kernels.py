@@ -10,7 +10,6 @@ from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba import mamba_scan
 from repro.kernels.rwkv6 import rwkv6_chunked
-from repro.kernels.support_margin import threshold_ranges, uncertain_mask
 
 
 def tol(dtype):

@@ -3,16 +3,14 @@
 The sweep engine's TPU data plane (``support_margin`` batched kernels, the
 ``median_cut`` scan, and the fused MAXMARG support/violation kernel) must be
 testable in CPU CI, not just on TPU hardware.  This module forces Pallas
-interpretation — via ``pltpu.force_tpu_interpret_mode`` where this jax
-version has it, else per-call ``interpret=True`` — and checks every kernel
+interpretation — ``pltpu.force_tpu_interpret_mode`` plus per-call
+``interpret=True`` — and checks every kernel
 against its pure-jnp oracle on engine-shaped inputs (label-0 padding rows,
 disallowed directions, ±inf range sentinels).
 
 These tests run in the CI ``bench-smoke`` job alongside the BENCH schema
 gate, so a kernel regression cannot hide behind a TPU-only test plan.
 """
-
-import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -21,16 +19,6 @@ import pytest
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops, ref
-
-
-def _interpret_ctx():
-    """The strongest interpret forcing this jax exposes: the global
-    force-TPU-interpret context when available (newer jax), else a null
-    context — each call below also passes interpret=True explicitly, so the
-    kernels interpret either way."""
-    if hasattr(pltpu, "force_tpu_interpret_mode"):
-        return pltpu.force_tpu_interpret_mode()
-    return contextlib.nullcontext()
 
 
 def _sweep_inputs(B=4, m=96, n=200, d=2, seed=7):
@@ -50,7 +38,7 @@ def _sweep_inputs(B=4, m=96, n=200, d=2, seed=7):
 
 def test_threshold_ranges_batched_interpret():
     V, X, y, *_ = _sweep_inputs()
-    with _interpret_ctx():
+    with pltpu.force_tpu_interpret_mode():
         lo, hi = ops.support_ranges_batch(V, X, y, interpret=True)
     loe, hie = ref.threshold_ranges_batch_ref(V, X, y)
     for got, want in ((lo, loe), (hi, hie)):
@@ -61,7 +49,7 @@ def test_threshold_ranges_batched_interpret():
 
 def test_uncertain_mask_batched_interpret():
     V, X, y, ok, lo, hi = _sweep_inputs()
-    with _interpret_ctx():
+    with pltpu.force_tpu_interpret_mode():
         mask = ops.support_uncertain_batch(V, ok, lo, hi, X, y,
                                            interpret=True)
     want = ref.uncertain_mask_batch_ref(V, ok, lo, hi, X, y)
@@ -70,7 +58,7 @@ def test_uncertain_mask_batched_interpret():
 
 def test_median_cut_batched_interpret_bit_for_bit():
     V, X, y, ok, lo, hi = _sweep_inputs()
-    with _interpret_ctx():
+    with pltpu.force_tpu_interpret_mode():
         got = ops.support_median_cut_batch(V, ok.astype(jnp.float32), lo, hi,
                                            X, y, interpret=True)
     want = ref.median_cut_scores_batch_ref(V, ok, lo, hi, X, y)
@@ -89,7 +77,7 @@ def test_median_extremes_batched_interpret_bit_for_bit():
     yW = yW.at[0, 0].set(1)      # a node with no negative class
     yW = yW.at[1, 2].set(0)      # a fully padded node
     v = jax.random.normal(ks[3], (B, d))
-    with _interpret_ctx():
+    with pltpu.force_tpu_interpret_mode():
         got = ops.support_extremes_batch(v, XW, yW, interpret=True)
     want = ref.median_extremes_batch_ref(v, XW, yW)
     for g, e in zip(got, want):
@@ -108,7 +96,7 @@ def test_maxmarg_turn_scan_interpret_bit_for_bit(max_support, viol_ship):
     y = y * jax.random.bernoulli(ks[5], 0.8, (B, k, n))
     w = jax.random.normal(ks[6], (B, d))
     b = jax.random.normal(ks[7], (B,))
-    with _interpret_ctx():
+    with pltpu.force_tpu_interpret_mode():
         got = ops.support_violation_batch(
             w, b, K, yK, X, y, max_support=max_support, viol_ship=viol_ship,
             interpret=True)
@@ -134,27 +122,28 @@ def _pegasos_inputs(B, N, d, seed=3, found_frac=0.3):
 
 
 def test_pegasos_stage_interpret_bit_for_bit():
-    """Lane-aligned d + single N-tile: the kernel's op sequence is exactly
-    the jnp twin's, so every output (including the fused latch) must match
+    """Lane-aligned point axis + single N-tile: the kernel's op sequence is
+    exactly the jnp twin's (points sit on the 128-lane axis, so N=128 needs
+    no padding), and every output (including the fused latch) must match
     bit-for-bit through the interpreter."""
-    args = _pegasos_inputs(B=6, N=48, d=8)
+    args = _pegasos_inputs(B=6, N=128, d=8)
     want = ref.pegasos_stage_batch_ref(*args, nsteps=60)
-    with _interpret_ctx():
+    with pltpu.force_tpu_interpret_mode():
         got = ops.pegasos_stage(*args, nsteps=60, use_pallas=True,
-                                interpret=True, block_b=8, block_n=64,
+                                interpret=True, block_b=8, block_n=128,
                                 unroll=1)
     for g, e in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
 
 
 def test_pegasos_stage_interpret_tiled_grid():
-    """Multi-block grid with unaligned d and N: the VMEM gradient
-    accumulation across N-tiles and the d-lane padding reassociate the
-    contractions, so floats are allclose while the latch decisions
-    (found / which w_best was taken) stay bit-equal."""
+    """Multi-block grid with unaligned N: the VMEM gradient accumulation
+    across N-tiles and the lane padding of the point axis reassociate the
+    sums, so floats are allclose while the latch decisions (found / which
+    w_best was taken) stay bit-equal."""
     args = _pegasos_inputs(B=5, N=70, d=12, seed=9)
     want = ref.pegasos_stage_batch_ref(*args, nsteps=60)
-    with _interpret_ctx():
+    with pltpu.force_tpu_interpret_mode():
         got = ops.pegasos_stage(*args, nsteps=60, use_pallas=True,
                                 interpret=True, block_b=2, block_n=16,
                                 unroll=1)
@@ -171,12 +160,12 @@ def test_pegasos_stage_interpret_warm_offset_and_latch():
     """t0 (the warm polish eta offset) threads through both paths
     identically, and an already-latched instance's w_best is never
     overwritten by a later separating stage."""
-    args = _pegasos_inputs(B=4, N=32, d=8, seed=5, found_frac=1.0)
+    args = _pegasos_inputs(B=4, N=128, d=8, seed=5, found_frac=1.0)
     want = ref.pegasos_stage_batch_ref(*args, nsteps=40, t0=1024.0)
-    with _interpret_ctx():
+    with pltpu.force_tpu_interpret_mode():
         got = ops.pegasos_stage(*args, nsteps=40, t0=1024.0,
                                 use_pallas=True, interpret=True,
-                                block_b=8, block_n=32, unroll=1)
+                                block_b=8, block_n=128, unroll=1)
     for g, e in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
     # all instances entered latched -> w_best must be the input w_best

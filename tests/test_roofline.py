@@ -33,13 +33,7 @@ def test_scan_multiplies_flops_by_trip_count():
     single = analyze_hlo(_compiled(lambda x: x @ x, (128, 128)).as_text())
     assert r["flops"] == pytest.approx(10 * single["flops"], rel=0.05)
     # XLA's own counter reports the body once — document the discrepancy.
-    # (Older jax returns cost_analysis() as a [dict]; normalize, and skip
-    # the XLA-counter comparison when flops are not exposed at all.)
     ca = c.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if "flops" not in ca:
-        pytest.skip("compiled.cost_analysis() exposes no flops on this jax")
     assert float(ca["flops"]) < r["flops"] / 5
 
 
@@ -91,3 +85,17 @@ ENTRY %main (p: f32[16,128]) -> f32[16,128] {
     payload = 16 * 128 * 4
     assert r["counts"]["all-reduce"] == 1
     assert r["total_bytes"] == int(payload * 2 * 7 / 8)
+
+
+def test_peaks_are_keyed_by_device_kind():
+    from repro.analysis.roofline import peaks
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9, 16e9)
+    assert "Google Cloud" in v5e.source
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "NVIDIA H100"])
+def test_unknown_device_kind_has_no_peaks(kind):
+    from repro.analysis.roofline import peaks
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks(kind)

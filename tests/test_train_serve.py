@@ -7,12 +7,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-if not hasattr(jax.sharding, "get_abstract_mesh"):
-    pytest.skip(
-        "model stack requires jax.sharding.get_abstract_mesh (jax >= 0.5.x); "
-        "pre-existing version skew on this container's jax, unrelated to the "
-        "protocol/engine code (ROADMAP.md)", allow_module_level=True)
-
 import repro.configs as C
 from repro.data.pipeline import DataConfig, synthetic_stream
 from repro.models import model as M
