@@ -50,14 +50,23 @@ queue and the session ledger round-trip, and the fault schedule is a pure
 hash of ``(seed, session id, pool turn)`` — no RNG state — so a restored
 pool replays the identical fault/eviction/retry sequence and unaffected
 sessions finish bit-exact (tests/test_session_pool.py pins all of it).
+
+Tracing: a pool turn writes ``jax.profiler.TraceAnnotation`` spans, which
+only a running profiler records.  ``pool.step`` holds ``pool.admit``
+(``rows`` admitted, ``nbytes`` handed to ``_admit_rows``, ``wait_us``
+summed from submit to admission), ``pool.dispatch`` (``rows`` live,
+``block`` pinned), ``pool.view`` and ``pool.evict`` (``rows``).  Every
+argument comes from shapes and counts at hand, never from a device read.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import json
 import os
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -384,6 +393,9 @@ class _Pending:
     selector: str = "median"   # per-session family (unified pools)
     seed: int = 0              # Vitter PRNG seed (SAMPLING sessions)
     res_cap: int = 0           # ε-net reservoir rows (SAMPLING sessions)
+    # host clock at submit (or at restore), read only for the admission
+    # span's queue wait: never in sessions, stats or checkpoints
+    submitted: float = dataclasses.field(default_factory=time.perf_counter)
 
 
 class SessionPool:
@@ -459,6 +471,13 @@ class SessionPool:
         # empty slots are born done: the dispatch mask is host-side anyway,
         # and done=True keeps them inert even if gathered as padding
         self.state = jax.tree_util.tree_map(jnp.asarray, state0)
+        # host bytes one admission wave hands _admit_rows: admit_block rows
+        # of data and fresh state (every leaf leads with the slot axis) and
+        # the int32 index block
+        row_bytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(
+            (self.data, state0))) // W
+        self._wave_nbytes = config.admit_block * (row_bytes + 4)
+        self._key_logged = False
 
         self.pool_turn = 0
         self._next_sid = 0
@@ -564,9 +583,22 @@ class SessionPool:
         """Refill empty slots from the pending queue in FIFO order, in
         fixed ``admit_block``-sized scatter waves (tail slots carry the
         out-of-range index W, dropped on device)."""
+        free = np.flatnonzero(self.slot_state == SLOT_EMPTY)
+        n = min(len(self.pending), free.size)
+        if not n:
+            return
+        now = time.perf_counter()
+        wait_s = sum(now - p.submitted
+                     for p in itertools.islice(self.pending, n))
+        waves = -(-n // self.cfg.admit_block)
+        with jax.profiler.TraceAnnotation(
+                "pool.admit", rows=n, nbytes=waves * self._wave_nbytes,
+                wait_us=round(wait_s * 1e6)):
+            self._admit_waves(free)
+
+    def _admit_waves(self, free: np.ndarray):
         cfg = self.cfg
         W, A = cfg.slots, cfg.admit_block
-        free = np.flatnonzero(self.slot_state == SLOT_EMPTY)
         while self.pending and free.size:
             take = min(len(self.pending), free.size, A)
             batch = [self.pending.popleft() for _ in range(take)]
@@ -631,13 +663,18 @@ class SessionPool:
         CONSTRUCTION.  A saturated pool (the steady state the service
         optimizes for) dispatches a full block anyway, so the cost is
         confined to drain tails and the worst-case transcript width.  The
-        key is appended to ``hotloop.KEY_LOG`` so the recompile gates
-        cover pool traffic too."""
+        key is appended to ``hotloop.KEY_LOG`` at the pool's first dispatch
+        (it never changes after), so the recompile gates cover pool traffic
+        too while a long-lived pool's log stays bounded."""
         cfg = self.cfg
-        fn, args, kw = self.turn_call(rows)
-        hotloop.KEY_LOG.append(
-            (args[-2].shape[0], cfg.cap, False, False))
-        self.state = fn(*args, **kw)
+        block = _round_up(cfg.slots, hotloop.BATCH_MULT)  # turn_call's idx
+        if not self._key_logged:
+            hotloop.KEY_LOG.append((block, cfg.cap, False, False))
+            self._key_logged = True
+        with jax.profiler.TraceAnnotation("pool.dispatch", rows=rows.size,
+                                          block=block):
+            fn, args, kw = self.turn_call(rows)
+            self.state = fn(*args, **kw)
         self.stats["dispatches"] += 1
 
     def turn_call(self, rows: np.ndarray):
@@ -751,6 +788,7 @@ class SessionPool:
 
     # -- the pool turn ------------------------------------------------------
 
+    @functools.partial(jax.profiler.annotate_function, name="pool.step")
     def step_pool(self):
         """One pool turn: admit → draw faults → dispatch survivors →
         corrupt → screen invariants → quarantine/evict → checkpoint."""
@@ -816,7 +854,8 @@ class SessionPool:
         # -- supervision screen (one (5, W) transfer) -----------------------
         viewer = {"median": _view_median, "maxmarg": _view_maxmarg,
                   "unified": _view_unified}[cfg.selector]
-        view = np.asarray(viewer(self.state))
+        with jax.profiler.TraceAnnotation("pool.view"):
+            view = np.asarray(viewer(self.state))
         done, conv, fills, nan, bits = view
         live = self.slot_state == SLOT_LIVE       # minus fresh quarantines
 
@@ -844,7 +883,8 @@ class SessionPool:
             | (live & (done > 0))
             | (live & (self.turns_done >= cfg.max_turns)))
         if evict.size:
-            self._evict(evict)
+            with jax.profiler.TraceAnnotation("pool.evict", rows=evict.size):
+                self._evict(evict)
 
         self.pool_turn += 1
         self.stats["pool_turns"] += 1

@@ -18,6 +18,11 @@ probabilities are the paper's one-way sampling semantics.
 Callers with ready-made shards can skip the stream and :meth:`submit`
 directly.
 
+Under ``jax.profiler``, :meth:`open` and :meth:`close` write the spans
+``serve.open`` and ``serve.close`` beside the pool's ``pool.*`` spans.
+:meth:`feed` writes none: it runs many times a session, and a span there
+would cost a measurable share of ingest while traced.
+
 The token-decode stub this package's seed shipped lives on as
 ``repro.serve.engine.TokenServingEngine`` — unrelated to protocol serving.
 """
@@ -25,8 +30,10 @@ The token-decode stub this package's seed shipped lives on as
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.sampling import Reservoir
@@ -86,6 +93,7 @@ class ProtocolService:
 
     # -- streaming ingest ---------------------------------------------------
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.open")
     def open(self, eps: Optional[float] = None,
              reservoir_capacity: Optional[int] = None,
              selector: Optional[str] = None, seed: int = 0) -> int:
@@ -120,6 +128,7 @@ class ProtocolService:
             raise ValueError(f"node {node} outside 0..{self.cfg.k - 1}")
         sess.reservoirs[node].add_batch(X, y)
 
+    @functools.partial(jax.profiler.annotate_function, name="serve.close")
     def close(self, handle: int) -> int:
         """Finalize a streaming session: take each node's reservoir snapshot
         (the filled rows only — the pool pads to its pinned ``n_pad`` with

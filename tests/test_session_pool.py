@@ -395,3 +395,13 @@ def test_second_identical_run_compiles_nothing():
     _run_pool(workload, schedule=CHAOS)
     assert _pool_cache_entries() - entries0 == 0
     assert len(set(hotloop.KEY_LOG[keys0:])) == 1
+
+
+def test_key_log_records_the_pinned_key_once_per_pool():
+    """The pinned dispatch key goes into ``hotloop.KEY_LOG`` at a pool's
+    first dispatch only, so a long-lived pool's log does not grow with
+    its turns."""
+    keys0 = len(hotloop.KEY_LOG)
+    pool = _run_pool(_workload(6, seed=4))
+    assert pool.stats["dispatches"] > 1
+    assert len(hotloop.KEY_LOG) - keys0 == 1
