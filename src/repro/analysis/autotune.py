@@ -17,7 +17,10 @@ and deterministic:
    table (an accelerator other than a TPU) is an error, not CPU tiles.
 
 ``unroll`` only affects the jnp ref twin's ``fori_loop`` (the CPU fast
-path); ``block_b``/``block_n`` only affect the Pallas launch.  Both live in
+path); ``block_b``/``block_n`` only affect the kernel's streamed grid — the
+resident path, which ``ops.pegasos_stage`` takes wherever
+:func:`vmem_bytes` of an 8-instance block over the whole lane-padded N fits
+``kernels.pegasos.RESIDENT_VMEM_BUDGET``, has no block shape.  Both live in
 one entry so a bucket is tuned once per device kind.
 
 The search half (:func:`search_bucket` / the ``__main__`` CLI) times each
@@ -148,7 +151,9 @@ CANDIDATE_UNROLL = (1, 2, 4)
 
 
 def vmem_bytes(block_b: int, block_n: int, d: int) -> int:
-    """Resident f32 working set of one grid step (double-buffered tiles)."""
+    """Resident f32 working set of one grid step (double-buffered tiles):
+    the streamed grid's candidate filter and, at ``block_b = 8`` and
+    ``block_n = N_pad``, the resident path's fit check."""
     tiles = block_b * block_n * (d + 1) * 2          # X + y, double-buffered
     scratch = block_b * (2 * d + 3)                  # w, g, b, gb, mm
     return 4 * (tiles + scratch)

@@ -9,7 +9,7 @@ check what the chip computes), (c) restores the caller's shapes.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -306,8 +306,15 @@ def support_extremes_batch(
 
 
 # ---------------------------------------------------------------------------
-# tiled Pegasos solver stage (MAXMARG refit inner loop)
+# Pegasos solver stage (MAXMARG refit inner loop)
 # ---------------------------------------------------------------------------
+
+# every Pallas stage call appends (B, N_pad, d, path) here when traced (or
+# called eagerly): path is "resident" or "streamed" (``kernels/pegasos.py``),
+# N_pad the point axis padded to the lane tile.  Tests and chip runs read
+# which path a shape took; callers clear it.
+PEGASOS_PATH_LOG: List[Tuple[int, int, int, str]] = []
+
 
 def pegasos_stage(
     X: jnp.ndarray,                # (B, N, d) f32; label-0 rows = padding
@@ -331,13 +338,16 @@ def pegasos_stage(
     """One fused Pegasos λ stage + first-0-error latch behind one call.
 
     The solver's single dispatch point (``_svm_solve_batch(kernel=True)``):
-    Pallas tiled kernel on TPU (auto-interpret elsewhere, like every other
+    Pallas kernel on TPU (auto-interpret elsewhere, like every other
     wrapper here), its jnp twin (``ref.pegasos_stage_batch_ref``) when
-    ``use_pallas`` resolves False.  Block shapes / unroll default from the
+    ``use_pallas`` resolves False.  The kernel's resident path is taken
+    whenever an 8-instance block of the lane-padded fit set fits
+    ``pegasos.RESIDENT_VMEM_BUDGET`` by ``analysis.autotune.vmem_bytes``,
+    its streamed grid otherwise.  Block shapes / unroll default from the
     committed autotune cache (``analysis.autotune.lookup_tile``) with its
-    deterministic fallback.  Returns ``(w, b, mmin, found, w_best,
-    b_best)``; ``mmin`` follows the kernel mask convention (``pegasos.BIG``
-    where no valid rows).
+    deterministic fallback; the resident path uses no block shape.
+    Returns ``(w, b, mmin, found, w_best, b_best)``; ``mmin`` follows the
+    kernel mask convention (``pegasos.BIG`` where no valid rows).
     """
     B, N, d = X.shape
     use_pallas = on_tpu() if use_pallas is None else use_pallas
@@ -353,8 +363,15 @@ def pegasos_stage(
             X, y, nv, w, b, lam, found, w_best, b_best,
             nsteps=nsteps, t0=t0, unroll=unroll)
 
-    bb = min(block_b, max(B, 1))
-    bn = _tile(N, block_n, _LANES)
+    n_pad = _round_up(N, _LANES)
+    resident = (_autotune.vmem_bytes(_pg.SUBLANES, n_pad, d)
+                <= _pg.RESIDENT_VMEM_BUDGET)
+    PEGASOS_PATH_LOG.append((B, n_pad, d,
+                             "resident" if resident else "streamed"))
+    if resident:
+        bb, bn = _pg.SUBLANES, n_pad
+    else:
+        bb, bn = min(block_b, max(B, 1)), _tile(N, block_n, _LANES)
     f32 = jnp.float32
 
     def one(a, value=0.0):         # (B,) -> block-padded (B', 1, 1)
@@ -371,6 +388,7 @@ def pegasos_stage(
         one(nv, 1.0), col(w), one(b), one(lam, 1.0),
         _pad_to(found.astype(jnp.int32), 0, bb)[:, None, None],
         col(w_best), one(b_best), nsteps=nsteps, t0=t0,
-        block_b=bb, block_n=bn, interpret=_interp(interpret))
+        block_b=bb, block_n=bn, resident=resident,
+        interpret=_interp(interpret))
     return (w_o[:B, :, 0], b_o[:B, 0, 0], mm_o[:B, 0, 0],
             f_o[:B, 0, 0] != 0, wb_o[:B, :, 0], bb_o[:B, 0, 0])

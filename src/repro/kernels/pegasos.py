@@ -1,36 +1,44 @@
-"""Tiled Pallas kernel for the batched Pegasos λ-stage (the MAXMARG refit).
+"""Pallas kernels for the batched Pegasos λ-stage (the MAXMARG refit).
 
 ``core.classifiers._svm_solve_batch`` runs every hard-margin refit as plain
 vmapped XLA Pegasos over ``(B, N, d)``: one ``fori_loop`` step per gradient
-pass, with the d-contraction spelled as d broadcast multiply-adds.  This
-kernel is the tiled deployment artifact for that loop:
+pass, with the d-contraction spelled as d broadcast multiply-adds.  On a TPU
+``kernel=True`` runs each λ stage as one launch of
+``pegasos_stage_batched``, which has two paths; ``ops.pegasos_stage``
+chooses one from the shape alone:
 
-* grid ``(B/block_b, nsteps+1, N/block_n)`` — instances in parallel blocks,
-  the Pegasos step axis sequential, N-tiles innermost;
-* points sit on the lane axis: the fit set comes transposed as (B, d, N)
-  with labels (B, 1, N), and every per-instance vector is a (d, 1) or
-  (1, 1) column of a (B, d, 1) / (B, 1, 1) operand, so each block's
-  trailing two dims are whole or (8, 128)-aligned;
-* each step's two contractions are spelled over the small d: margins as d
-  multiply-adds in coordinate order (the arithmetic of the classic solver
-  and of the ``ref.pegasos_stage_batch_ref`` twin), the hinge gradient as a
-  multiply and a lane reduction per coordinate, accumulated across N-tiles
-  in an f32 VMEM scratch (``g_s``/``gb_s``);
-* the separator itself lives in VMEM scratch across the whole stage — one
-  kernel launch covers a *whole λ stage* (nsteps updates + the trailing
-  margin scan), not one ``fori_loop`` step per dispatch;
-* the first-0-error latch of ``_svm_solve_batch`` is fused: the final grid
-  step folds the stage's min-margin scan into the ``found``/``w_best``/
-  ``b_best`` latch update, so the stage-annealing caller reads latched
-  results straight out of the launch;
-* masked-pad path: label-0 rows contribute no hinge violations and the
-  gradient normalizes by the caller-supplied per-instance valid count
-  ``nv`` — compacted hot-loop fills and tile padding ride the same mask.
+* **resident** — when an 8-instance block's fit set, double-buffered,
+  fits ``RESIDENT_VMEM_BUDGET`` (``analysis.autotune.vmem_bytes`` at
+  ``block_b = 8``, ``block_n = N_pad``).  Grid ``(B/8,)``: the block's
+  points and labels are DMA'd into VMEM once and stay there for all
+  ``nsteps`` updates and the closing margin scan, which run as in-kernel
+  loops.  Instances sit on sublanes — the fit set as (d, B, N), labels as
+  (B, N) — so each coordinate is one full (8, N) slab; a step walks N in
+  128-lane chunks, computing each chunk's margins and folding its hinge
+  gradient into per-lane accumulators, which one lane reduction per step
+  closes.  The separator is carried in registers.
+* **streamed** — every other shape.  Grid ``(B/block_b, nsteps+1,
+  N/block_n)``: instances in parallel blocks, the Pegasos step axis
+  sequential, N-tiles innermost, so the fit set streams from HBM on every
+  step.  Points sit on the lane axis — the fit set transposed as (B, d, N)
+  with labels (B, 1, N), every per-instance vector a (d, 1) or (1, 1)
+  column — and the hinge gradient is a multiply and a lane reduction per
+  coordinate and N-tile, accumulated across N-tiles in f32 VMEM scratch
+  (``g_s``/``gb_s``).  Block shapes come from the committed tuning cache
+  (``kernels/tuning_cache.json`` via ``analysis.autotune.lookup_tile``).
 
-Block shapes come from the committed tuning cache
-(``kernels/tuning_cache.json`` via ``analysis.autotune.lookup_tile``); the
-``ops.pegasos_stage`` wrapper pads/dispatches and falls back to the jnp
-twin off-TPU.
+Both paths share the arithmetic: f32 throughout, margins as d
+multiply-adds in coordinate order (the arithmetic of the classic solver
+and of the ``ref.pegasos_stage_batch_ref`` twin), the same η schedule,
+ball projection and ``nv`` normalisation.  Only the association of the
+N-sum differs between them.  Both fuse the first-0-error latch of
+``_svm_solve_batch``: the stage's min-margin scan folds into the
+``found``/``w_best``/``b_best`` latch update, so the stage-annealing
+caller reads latched results straight out of the launch.  Label-0 rows
+contribute no hinge violations and the gradient normalizes by the
+caller-supplied per-instance valid count ``nv`` — compacted hot-loop
+fills and tile padding ride the same mask.  The ``ops.pegasos_stage``
+wrapper pads/dispatches and falls back to the jnp twin off-TPU.
 """
 
 from __future__ import annotations
@@ -43,6 +51,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BIG = 1e30  # mask constant for the min-margin scan of an all-padding block
+
+LANES = 128       # the resident path walks the point axis in chunks this wide
+SUBLANES = 8      # instances per resident block: one per sublane
+
+#: VMEM (bytes) one resident block's working set may take — its points and
+#: labels double-buffered, as ``analysis.autotune.vmem_bytes`` counts them.
+#: The resident launch asks the compiler for this much scoped VMEM plus
+#: ``_VMEM_HEADROOM`` for the per-instance vectors and its own scratch.
+RESIDENT_VMEM_BUDGET = 24 << 20
+_VMEM_HEADROOM = 8 << 20
+# lane chunks per iteration of the resident path's walk: on a v5e the
+# closed MAXMARG cell's stage takes 15.9 ms at 1, 11.2 at 2, 8.4 at 4 and 8
+_CHUNK_UNROLL = 4
 
 
 def decide(XT: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -129,8 +150,129 @@ def _pegasos_stage_kernel(
         bbest_out[...] = jnp.where(take, b_s[...], bb_ref[...])
 
 
+def _resident_stage_kernel(
+    x_ref, y_ref, nv_ref, w0_ref, b0_ref, lam_ref, found_ref, wb_ref, bb_ref,
+    w_out, b_out, mmin_out, found_out, wbest_out, bbest_out,
+    *, nsteps: int, t0: float, unroll: int,
+):
+    """One λ stage for 8 instances whose fit set stays in VMEM throughout.
+
+    ``x_ref`` is the block's (d, 8, N) points and ``y_ref`` its (8, N)
+    labels; per-instance vectors are (d, 8, 1) or (8, 1).  The separator
+    is a loop carry; every pass over the points walks N in 128-lane chunks.
+    """
+    d, rows, n = x_ref.shape
+    f32 = jnp.float32
+    lam = lam_ref[...]                                   # (8, 1)
+    nv = nv_ref[...]
+
+    def walk(w, b, fold, acc):
+        """Fold ``fold(x, yv, m, acc)`` over the chunks of the fit set; ``m``
+        is a chunk's (8, 128) margins, the multiply-adds of ``decide``."""
+        wl = [jnp.broadcast_to(w[j], (rows, LANES)) for j in range(d)]
+        bl = jnp.broadcast_to(b, (rows, LANES))
+
+        def chunk(c, acc):
+            at = pl.ds(pl.multiple_of(c * LANES, LANES), LANES)
+            x = x_ref[:, :, at]                          # (d, 8, 128)
+            yv = y_ref[:, at]                            # (8, 128)
+            m = x[0] * wl[0]
+            for j in range(1, d):
+                m = m + x[j] * wl[j]
+            return fold(x, yv, yv * (m + bl), acc)
+
+        def group(i, acc):         # Mosaic loops unroll fully or not at all
+            for k in range(unroll):
+                acc = chunk(i * unroll + k, acc)
+            return acc
+
+        chunks = n // LANES
+        acc = jax.lax.fori_loop(0, chunks // unroll, group, acc)
+        for c in range(chunks - chunks % unroll, chunks):
+            acc = chunk(c, acc)
+        return acc
+
+    def grad(x, yv, m, acc):
+        g, gb = acc
+        # ((m < 1) & valid) * y: label-0 rows give 0 either way
+        vy = jnp.where(m < 1.0, yv, 0.0)
+        return g + vy[None] * x, gb + vy
+
+    def step(s, carry):
+        w, b = carry
+        g, gb = walk(w, b, grad, (jnp.zeros((d, rows, LANES), f32),
+                                  jnp.zeros((rows, LANES), f32)))
+        g = jnp.sum(g, axis=2, keepdims=True)            # (d, 8, 1)
+        gb = -jnp.sum(gb, axis=1, keepdims=True) / nv    # (8, 1)
+        eta = 1.0 / (lam * (s.astype(f32) + 2.0 + t0))
+        gw = lam * w - g / nv
+        w2 = w - eta * gw
+        b2 = b - eta * gb
+        nrm = jnp.sqrt(jnp.sum(w2 * w2, axis=0))
+        scale = jnp.minimum(1.0, (1.0 / jnp.sqrt(lam)) / (nrm + 1e-12))
+        return w2 * scale, b2 * scale
+
+    w, b = jax.lax.fori_loop(0, nsteps, step, (w0_ref[...], b0_ref[...]))
+    mm = walk(w, b, lambda x, yv, m, acc: jnp.minimum(
+        acc, jnp.where(yv != 0.0, m, BIG)), jnp.full((rows, LANES), BIG, f32))
+    mm = jnp.min(mm, axis=1, keepdims=True)              # (8, 1)
+    ok = mm > 0.0                                        # BIG ⇒ no valid rows
+    found_in = found_ref[...] != 0
+    take = ok & ~found_in
+    w_out[...] = w
+    b_out[...] = b
+    mmin_out[...] = mm
+    found_out[...] = (found_in | ok).astype(jnp.int32)
+    wbest_out[...] = jnp.where(take[None], w, wb_ref[...])
+    bbest_out[...] = jnp.where(take, b, bb_ref[...])
+
+
+def _resident_stage(XT, y, nv, w, b, lam, found, w_best, b_best, *,
+                    nsteps: int, t0: float, interpret: bool):
+    """The resident path of ``pegasos_stage_batched``: relays the operands
+    with instances on sublanes, launches grid ``(B/8,)``, and returns the
+    outputs in the streamed path's layouts."""
+    B, d, N = XT.shape
+    assert B % SUBLANES == 0 and N % LANES == 0, (B, N)
+    f32 = jnp.float32
+
+    def col(a):                    # (B, d, 1) <-> (d, B, 1)
+        return jnp.transpose(a, (1, 0, 2))
+
+    kernel = functools.partial(_resident_stage_kernel, nsteps=nsteps, t0=t0,
+                               unroll=_CHUNK_UNROLL)
+    one = pl.BlockSpec((SUBLANES, 1), lambda i: (i, 0))
+    vec = pl.BlockSpec((d, SUBLANES, 1), lambda i: (0, i, 0))
+    w_o, b_o, mm_o, f_o, wb_o, bb_o = pl.pallas_call(
+        kernel,
+        grid=(B // SUBLANES,),
+        in_specs=[
+            pl.BlockSpec((d, SUBLANES, N), lambda i: (0, i, 0)),
+            pl.BlockSpec((SUBLANES, N), lambda i: (i, 0)),
+            one, vec, one, one, one, vec, one,
+        ],
+        out_specs=[vec, one, one, one, vec, one],
+        out_shape=[
+            jax.ShapeDtypeStruct((d, B, 1), f32),
+            jax.ShapeDtypeStruct((B, 1), f32),
+            jax.ShapeDtypeStruct((B, 1), f32),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32),
+            jax.ShapeDtypeStruct((d, B, 1), f32),
+            jax.ShapeDtypeStruct((B, 1), f32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=RESIDENT_VMEM_BUDGET + _VMEM_HEADROOM),
+        interpret=interpret,
+    )(col(XT), y[:, 0, :], nv[:, :, 0], col(w), b[:, :, 0], lam[:, :, 0],
+      found[:, :, 0], col(w_best), b_best[:, :, 0])
+    return (col(w_o), b_o[..., None], mm_o[..., None], f_o[..., None],
+            col(wb_o), bb_o[..., None])
+
+
 @functools.partial(jax.jit, static_argnames=("nsteps", "t0", "block_b",
-                                             "block_n", "interpret"))
+                                             "block_n", "resident",
+                                             "interpret"))
 def pegasos_stage_batched(
     XT: jnp.ndarray,               # (B, d, N) f32 fit sets, transposed
     y: jnp.ndarray,                # (B, 1, N) f32 in {+1, -1, 0}; 0 = padding
@@ -146,17 +288,23 @@ def pegasos_stage_batched(
     t0: float = 0.0,
     block_b: int = 8,
     block_n: int = 512,
+    resident: bool = False,
     interpret: bool = False,
 ):
     """One fused Pegasos λ stage + first-0-error latch as one pallas_call.
 
-    Shapes must tile evenly (the ``ops.pegasos_stage`` wrapper pads).
+    ``resident`` takes the resident path (B a multiple of 8, N of 128;
+    ``block_b``/``block_n`` unused), else the streamed grid, whose shapes
+    must tile evenly (the ``ops.pegasos_stage`` wrapper pads).
     Returns ``(w, b, mmin, found, w_best, b_best)`` in the operand layouts;
     ``mmin`` uses the kernel mask constant ``BIG`` (not inf) for instances
     with no valid rows — callers that need the inf convention recompute
     margins themselves (``_svm_solve_batch`` does, for canonicalization
     only).
     """
+    if resident:
+        return _resident_stage(XT, y, nv, w, b, lam, found, w_best, b_best,
+                               nsteps=nsteps, t0=t0, interpret=interpret)
     B, d, N = XT.shape
     block_b = min(block_b, B)
     block_n = min(block_n, N)

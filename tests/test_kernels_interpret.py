@@ -18,7 +18,8 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import ops, ref
+from repro.analysis import autotune
+from repro.kernels import ops, pegasos, ref
 
 
 def _sweep_inputs(B=4, m=96, n=200, d=2, seed=7):
@@ -121,7 +122,26 @@ def _pegasos_inputs(B, N, d, seed=3, found_frac=0.3):
     return X, y, nv, w, b, lam, found, w_best, b_best
 
 
-def test_pegasos_stage_interpret_bit_for_bit():
+@pytest.fixture
+def streamed(monkeypatch):
+    """Hold every shape over the resident budget, so the stage runs on the
+    streamed grid, and check that it did."""
+    monkeypatch.setattr(pegasos, "RESIDENT_VMEM_BUDGET", -1)
+    ops.PEGASOS_PATH_LOG.clear()
+    yield
+    assert {p for *_, p in ops.PEGASOS_PATH_LOG} == {"streamed"}
+
+
+def _resident_stage(args, **kw):
+    """The Pallas stage on the resident path, checked to have been taken."""
+    ops.PEGASOS_PATH_LOG.clear()
+    with pltpu.force_tpu_interpret_mode():
+        got = ops.pegasos_stage(*args, use_pallas=True, interpret=True, **kw)
+    assert ops.PEGASOS_PATH_LOG[-1][3] == "resident"
+    return got
+
+
+def test_pegasos_stage_interpret_bit_for_bit(streamed):
     """Lane-aligned point axis + single N-tile: the kernel's op sequence is
     exactly the jnp twin's (points sit on the 128-lane axis, so N=128 needs
     no padding), and every output (including the fused latch) must match
@@ -136,7 +156,7 @@ def test_pegasos_stage_interpret_bit_for_bit():
         np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
 
 
-def test_pegasos_stage_interpret_tiled_grid():
+def test_pegasos_stage_interpret_tiled_grid(streamed):
     """Multi-block grid with unaligned N: the VMEM gradient accumulation
     across N-tiles and the lane padding of the point axis reassociate the
     sums, so floats are allclose while the latch decisions (found / which
@@ -156,7 +176,7 @@ def test_pegasos_stage_interpret_tiled_grid():
                                        rtol=1e-5, atol=1e-6)
 
 
-def test_pegasos_stage_interpret_warm_offset_and_latch():
+def test_pegasos_stage_interpret_warm_offset_and_latch(streamed):
     """t0 (the warm polish eta offset) threads through both paths
     identically, and an already-latched instance's w_best is never
     overwritten by a later separating stage."""
@@ -172,3 +192,63 @@ def test_pegasos_stage_interpret_warm_offset_and_latch():
     np.testing.assert_array_equal(np.asarray(got[4]), np.asarray(args[7]))
     np.testing.assert_array_equal(np.asarray(got[3]),
                                   np.ones(4, bool))
+
+
+def test_pegasos_resident_interpret_bit_for_bit():
+    """One lane-aligned 128-lane chunk: the resident path's op sequence is
+    the jnp twin's, so every output matches bit-for-bit."""
+    args = _pegasos_inputs(B=6, N=128, d=8)
+    want = ref.pegasos_stage_batch_ref(*args, nsteps=60)
+    got = _resident_stage(args, nsteps=60)
+    for g, e in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+
+
+def test_pegasos_resident_interpret_many_chunks():
+    """Unaligned N over nine lane chunks and B not a multiple of 8 (two
+    blocks, pad instances): per-lane accumulation across chunks
+    reassociates the N-sums, so floats are allclose and the latch bits
+    equal."""
+    args = _pegasos_inputs(B=11, N=1100, d=5, seed=9)
+    want = ref.pegasos_stage_batch_ref(*args, nsteps=60)
+    got = _resident_stage(args, nsteps=60)
+    names = ("w", "b", "mmin", "found", "w_best", "b_best")
+    for name, g, e in zip(names, got, want):
+        if name == "found":
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+        else:
+            np.testing.assert_allclose(np.asarray(g), np.asarray(e),
+                                       rtol=1e-5, atol=1e-6)
+
+
+def test_pegasos_resident_interpret_warm_offset_and_latch():
+    """t0 threads through the resident path as through the twin, and an
+    already-latched instance keeps its w_best."""
+    args = _pegasos_inputs(B=4, N=128, d=8, seed=5, found_frac=1.0)
+    want = ref.pegasos_stage_batch_ref(*args, nsteps=40, t0=1024.0)
+    got = _resident_stage(args, nsteps=40, t0=1024.0)
+    for g, e in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
+    np.testing.assert_array_equal(np.asarray(got[4]), np.asarray(args[7]))
+    np.testing.assert_array_equal(np.asarray(got[3]), np.ones(4, bool))
+
+
+@pytest.mark.parametrize("B,N,d,path", [
+    (32, 8192 + 296, 10, "resident"),    # the closed MAXMARG cell's stage
+    (32, 40000, 64, "streamed"),         # d=64 at tens of thousands of rows
+])
+def test_pegasos_path_follows_budget(B, N, d, path):
+    """The path is chosen from the shape: resident exactly when an
+    8-instance block of the lane-padded fit set fits the VMEM budget."""
+    n_pad = -(-N // 128) * 128
+    fits = autotune.vmem_bytes(8, n_pad, d) <= pegasos.RESIDENT_VMEM_BUDGET
+    assert fits == (path == "resident")
+    f32 = jnp.float32
+    sds = lambda *shape, dt=f32: jax.ShapeDtypeStruct(shape, dt)  # noqa: E731
+    ops.PEGASOS_PATH_LOG.clear()
+    jax.eval_shape(
+        lambda *a: ops.pegasos_stage(*a, nsteps=2000, use_pallas=True,
+                                     interpret=True),
+        sds(B, N, d), sds(B, N), sds(B), sds(B, d), sds(B), sds(B),
+        sds(B, dt=jnp.bool_), sds(B, d), sds(B))
+    assert ops.PEGASOS_PATH_LOG == [(B, n_pad, d, path)]

@@ -130,6 +130,23 @@ def test_pegasos_stage_compiles(one_chip, d):
     assert "tpu_custom_call" in txt
 
 
+@pytest.mark.parametrize("n,d,path", [
+    (8192 + 296, 10, "resident"),     # the closed MAXMARG cell's stage
+    (40000, 64, "streamed"),          # over the resident VMEM budget
+])
+def test_pegasos_stage_path_compiles(one_chip, n, d, path):
+    """Each path of the stage kernel compiles where the shape sends it."""
+    s = lambda *a, **k: _sds(one_chip, *a, **k)  # noqa: E731
+    ops.PEGASOS_PATH_LOG.clear()
+    txt = _compiled_text(
+        lambda *a: ops.pegasos_stage(*a, nsteps=2000, use_pallas=True,
+                                     interpret=False),
+        s((B, n, d)), s((B, n)), s((B,)), s((B, d)), s((B,)), s((B,)),
+        s((B,), jnp.bool_), s((B, d)), s((B,)))
+    assert "tpu_custom_call" in txt
+    assert [p for *_, p in ops.PEGASOS_PATH_LOG] == [path]
+
+
 def test_maxmarg_pool_dispatch_compiles(one_chip, monkeypatch):
     """The served MAXMARG path: a d=10, k=4 pool's pinned turn, traced as
     the chip traces it (the backend probe answers "tpu"), must compile with
