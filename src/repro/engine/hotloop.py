@@ -48,6 +48,7 @@ need).
 
 from __future__ import annotations
 
+import time
 from typing import Callable, List, Optional, Tuple
 
 import numpy as np
@@ -247,12 +248,25 @@ def run_hot(
     warm gate re-checks on device).  At most one wasted all-done masked
     dispatch runs at termination.
 
-    ``stats`` (optional dict) collects host-side observability: on sharded
-    sweeps every :func:`balanced_index` call folds its per-shard live-count
-    skew (:func:`shard_skew`) into ``stats["shard_skew_max"]`` /
-    ``stats["shard_skew_last"]`` and counts dispatches in
-    ``stats["shard_dispatches"]`` — the measurable rebalancing signal the
-    ROADMAP's skewed-shard item asks for.  Never read for decisions.
+    ``stats`` (optional dict) collects host-side observability, never read
+    for decisions.  Every sweep counts ``stats["turns"]`` (dispatches),
+    ``stats["live_rows"]`` (Σ live instances over dispatches),
+    ``stats["dispatched_rows"]`` (Σ rows the dispatches ran: B for a
+    full-batch turn, S·L for a sharded sub-batch turn, ``n_pad``
+    unsharded), ``stats["view_wait_s"]`` (host seconds blocked decoding
+    the host view) and ``stats["stage_shapes"]``, the count of dispatches
+    per ``(L, width, warm)`` with L the rows a device ran (the shape its
+    solver stages ran at).  On sharded sweeps every :func:`balanced_index`
+    call also folds its per-shard live-count skew (:func:`shard_skew`)
+    into ``stats["shard_skew_max"]`` / ``stats["shard_skew_last"]`` and
+    counts dispatches in ``stats["shard_dispatches"]`` — the measurable
+    rebalancing signal the ROADMAP's skewed-shard item asks for.
+
+    Tracing: each turn writes ``jax.profiler.TraceAnnotation`` spans, which
+    only a running profiler records: ``sweep.turn`` (``live``, ``rows``,
+    ``width``) from the turn's dispatch parameters to its view's enqueue,
+    holding ``sweep.dispatch`` around the dispatch call, and
+    ``sweep.view`` around each blocking decode of a host view.
     """
     B = int(state.done.shape[0])
     # the scatter-drop tail is a host-side constant: every pad slot carries
@@ -262,14 +276,43 @@ def run_hot(
     # host-side loop counter resumes from the common (max) value
     t = int(np.asarray(state.turn).max(initial=0))
 
+    if stats is not None:
+        for key in ("turns", "live_rows", "dispatched_rows"):
+            stats.setdefault(key, 0)
+        stats.setdefault("view_wait_s", 0.0)
+        stats.setdefault("stage_shapes", {})
+    S = shards or 1
+
+    def count(live, rows, width, use_warm):
+        if stats is not None:
+            stats["turns"] += 1
+            stats["live_rows"] += live
+            stats["dispatched_rows"] += rows
+            key = (rows // S, width, use_warm)
+            stats["stage_shapes"][key] = stats["stage_shapes"].get(key, 0) + 1
+
+    def decode(vh):
+        """The blocking read of a host view, timed into the stats."""
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("sweep.view"):
+            view = np.asarray(vh)
+        if stats is not None:
+            stats["view_wait_s"] += time.perf_counter() - t0
+        return view
+
     if not compact:
         while t < max_turns:
-            done, warm_ok, fills = np.asarray(host_view(state, t % k))
+            done, warm_ok, fills = decode(host_view(state, t % k))
             if bool(done.all()):
                 break
             act = np.flatnonzero(done == 0)
             use_warm = warm and t > 0 and bool(warm_ok[act].any())
-            state = dispatch_full(state, t=t, width=None, use_warm=use_warm)
+            count(len(act), B, cap, use_warm)
+            with jax.profiler.TraceAnnotation("sweep.turn", live=len(act),
+                                              rows=B, width=cap), \
+                    jax.profiler.TraceAnnotation("sweep.dispatch"):
+                state = dispatch_full(state, t=t, width=None,
+                                      use_warm=use_warm)
             t += 1
         return state
 
@@ -286,13 +329,13 @@ def run_hot(
         return act, width, use_warm
 
     def dispatch(state, act, width, use_warm, t):
+        """Dispatch turn t and enqueue its host view: ``(state, view)``."""
         n_act = len(act)
         if n_act == B:
             # full batch: the width compaction is the whole win — skip the
             # gather/scatter round-trip entirely
-            KEY_LOG.append((B, width, use_warm, t == 0))
-            return dispatch_full(state, t=t, width=width, use_warm=use_warm)
-        if shards:
+            idx = None
+        elif shards:
             idx, n_vec = balanced_index(act, B, shards)
             if stats is not None:
                 skew = shard_skew(n_vec)
@@ -301,40 +344,50 @@ def run_hot(
                     stats.get("shard_skew_max", 0.0), skew)
                 stats["shard_dispatches"] = \
                     stats.get("shard_dispatches", 0) + 1
-            KEY_LOG.append((len(idx), width, use_warm, t == 0))
-            return dispatch_sub(state, jnp.asarray(idx), jnp.asarray(n_vec),
-                                t=t, width=width, use_warm=use_warm)
-        n_pad = min(B, _round_up(n_act, BATCH_MULT))
-        idx = np.concatenate([act.astype(np.int32),
-                              pad_tail[:n_pad - n_act]])
-        KEY_LOG.append((n_pad, width, use_warm, t == 0))
-        return dispatch_sub(state, jnp.asarray(idx), jnp.int32(n_act),
-                            t=t, width=width, use_warm=use_warm)
+            n_arg = jnp.asarray(n_vec)
+        else:
+            n_pad = min(B, _round_up(n_act, BATCH_MULT))
+            idx = np.concatenate([act.astype(np.int32),
+                                  pad_tail[:n_pad - n_act]])
+            n_arg = jnp.int32(n_act)
+        rows = B if idx is None else len(idx)
+        KEY_LOG.append((rows, width, use_warm, t == 0))
+        count(n_act, rows, width, use_warm)
+        with jax.profiler.TraceAnnotation("sweep.turn", live=n_act,
+                                          rows=rows, width=width):
+            with jax.profiler.TraceAnnotation("sweep.dispatch"):
+                if idx is None:
+                    state = dispatch_full(state, t=t, width=width,
+                                          use_warm=use_warm)
+                else:
+                    state = dispatch_sub(state, jnp.asarray(idx), n_arg,
+                                         t=t, width=width,
+                                         use_warm=use_warm)
+            # enqueue BEFORE donation of this handle (next dispatch)
+            return state, host_view(state, (t + 1) % k)
 
     # one packed transfer per turn for everything the host needs; the seed
     # view is decoded synchronously (nothing to overlap with yet)
-    view = np.asarray(host_view(state, t % k))
+    view = decode(host_view(state, t % k))
     while t < max_turns:
         done, warm_ok, fills = view
         if bool(done.all()):
             break
         act, width, use_warm = params(done, warm_ok, fills, t, 0)
-        state = dispatch(state, act, width, use_warm, t)
-        vh = host_view(state, (t + 1) % k)     # enqueue BEFORE donation of
-        t += 1                                 # this handle (next dispatch)
+        state, vh = dispatch(state, act, width, use_warm, t)
+        t += 1
         if overlap and t < max_turns:
             # double buffer: dispatch turn t from the now-stale view before
             # blocking on the decode of turn t-1's view (vh)
             act_s, width_s, warm_s = params(done, warm_ok, fills, t,
                                             width_growth)
-            state = dispatch(state, act_s, width_s, warm_s, t)
-            vh2 = host_view(state, (t + 1) % k)
+            state, vh2 = dispatch(state, act_s, width_s, warm_s, t)
             t += 1
-            if bool(np.asarray(vh)[0].all()):
+            if bool(decode(vh)[0].all()):
                 # the speculated turn ran on an all-done batch: a masked
                 # no-op — results are untouched, only the turn counter moved
                 break
-            view = np.asarray(vh2)
+            view = decode(vh2)
         else:
-            view = np.asarray(vh)
+            view = decode(vh)
     return state

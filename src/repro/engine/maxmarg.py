@@ -442,7 +442,9 @@ def _sharded_dispatches(mesh, dspec, sspec, opts, donate):
 
     k, max_support, steps, stages, lam0, fused_kernel, solver_kernel = opts
 
-    def full(data, state, *, trans_width, warm, per_node):
+    # the names become the programs' names in a device trace
+    # (``jit__sharded_full_turn`` / ``jit__sharded_sub_turn``)
+    def _sharded_full_turn(data, state, *, trans_width, warm, per_node):
         def body(d, s):
             return step(d, s, k=k, max_support=max_support, steps=steps,
                         stages=stages, lam0=lam0, trans_width=trans_width,
@@ -452,7 +454,8 @@ def _sharded_dispatches(mesh, dspec, sspec, opts, donate):
         return shard_map(body, mesh=mesh, in_specs=(dspec, sspec),
                          out_specs=sspec, check_rep=False)(data, state)
 
-    def sub(data, state, idx, n_act, *, trans_width, warm, per_node):
+    def _sharded_sub_turn(data, state, idx, n_act, *, trans_width, warm,
+                          per_node):
         # idx is the (S·L,) per-shard block from hotloop.balanced_index and
         # n_act the (S,) per-shard live counts — each shard sees its (L,)
         # local slice and (1,) count and runs the plain gathered turn
@@ -470,8 +473,10 @@ def _sharded_dispatches(mesh, dspec, sspec, opts, donate):
 
     statics = ("trans_width", "warm", "per_node")
     dn = (1,) if donate else ()
-    return (jax.jit(full, static_argnames=statics, donate_argnums=dn),
-            jax.jit(sub, static_argnames=statics, donate_argnums=dn))
+    return (jax.jit(_sharded_full_turn, static_argnames=statics,
+                    donate_argnums=dn),
+            jax.jit(_sharded_sub_turn, static_argnames=statics,
+                    donate_argnums=dn))
 
 
 @functools.partial(jax.jit, static_argnames=("per_node",))
@@ -667,7 +672,10 @@ def run_instances(
     latch (jnp twin off-TPU; same TPU-only default).  ``mesh`` shards the hot path over a 1-D ("data",)
     device mesh (requires ``compact=True``); ``donate``/``overlap`` opt the
     per-turn dispatches into buffer donation and the double-buffered host
-    loop (mesh default: both on).
+    loop (mesh default: both on).  ``stats`` collects the hot loop's
+    counters (``hotloop.run_hot``).  Tracing: ``sweep.pack`` spans packing
+    and upload, ``sweep.collect`` the read-back of the results, and the
+    hot loop writes the per-turn ``sweep.*`` spans between them.
 
     Compile-key contract: ``max_epochs``, ``max_support``, ``steps``,
     ``stages``, ``k``, ``d``, ``per_node``, the kernel toggles, and the
@@ -688,8 +696,10 @@ def run_instances(
         fused_kernel = ops.on_tpu()
     if solver_kernel is None:
         solver_kernel = ops.on_tpu()
-    data, state0, k, _cap = pack_instances_maxmarg(
-        instances, max_epochs=max_epochs, max_support=max_support, mesh=mesh)
+    with jax.profiler.TraceAnnotation("sweep.pack"):
+        data, state0, k, _cap = pack_instances_maxmarg(
+            instances, max_epochs=max_epochs, max_support=max_support,
+            mesh=mesh)
     if warm or compact:
         final = run_hot(data, state0, k=k, max_turns=k * max_epochs,
                         max_support=max_support, steps=steps, stages=stages,
@@ -704,26 +714,27 @@ def run_instances(
                              fused_kernel=fused_kernel,
                              solver_kernel=solver_kernel)
 
-    converged = np.asarray(final.converged)
-    epochs = np.asarray(final.epochs)
-    h_w = np.asarray(final.h_w, np.float64)
-    h_b = np.asarray(final.h_b, np.float64)
-    latches = np.asarray(final.latches)
-    comm_np = type(final.comm)(*(np.asarray(a) for a in final.comm))
-    d = data.X.shape[3]
-    extra = {"engine": True, "batch": len(instances),
-             "selector": "maxmarg", "warm": warm, "compact": compact,
-             "per_node": per_node}
-    if mesh is not None:
-        extra["devices"] = int(mesh.shape["data"])
-    results: List[ProtocolResult] = []
-    for i in range(len(instances)):
-        h = clf.LinearSeparator(h_w[i], float(h_b[i]))
-        results.append(ProtocolResult(
-            h,
-            comm_np.summary(i, dim=d),
-            rounds=int(epochs[i]) if converged[i] else max_epochs,
-            converged=bool(converged[i]),
-            extra=dict(extra, warm_latches=int(latches[i])),
-        ))
+    with jax.profiler.TraceAnnotation("sweep.collect"):
+        converged = np.asarray(final.converged)
+        epochs = np.asarray(final.epochs)
+        h_w = np.asarray(final.h_w, np.float64)
+        h_b = np.asarray(final.h_b, np.float64)
+        latches = np.asarray(final.latches)
+        comm_np = type(final.comm)(*(np.asarray(a) for a in final.comm))
+        d = data.X.shape[3]
+        extra = {"engine": True, "batch": len(instances),
+                 "selector": "maxmarg", "warm": warm, "compact": compact,
+                 "per_node": per_node}
+        if mesh is not None:
+            extra["devices"] = int(mesh.shape["data"])
+        results: List[ProtocolResult] = []
+        for i in range(len(instances)):
+            h = clf.LinearSeparator(h_w[i], float(h_b[i]))
+            results.append(ProtocolResult(
+                h,
+                comm_np.summary(i, dim=d),
+                rounds=int(epochs[i]) if converged[i] else max_epochs,
+                converged=bool(converged[i]),
+                extra=dict(extra, warm_latches=int(latches[i])),
+            ))
     return results

@@ -166,3 +166,53 @@ def test_maxmarg_pool_dispatch_compiles(one_chip, monkeypatch):
     finally:
         jax.clear_caches()
     assert "tpu_custom_call" in txt
+
+
+@pytest.mark.parametrize("L", [32, 4])
+def test_sharded_sub_turn_compiles(topo, one_chip, monkeypatch, L):
+    """The four-chip MAXMARG sweep's sub-batch turn
+    (``maxmarg._sharded_sub_turn`` over a ("data",) mesh of the described
+    2x2 host; 128 instances, 32 a chip, 8192 points a node, d=10) at a
+    local batch of L rows, with the turn-scan and Pegasos kernels on: it
+    compiles, holds the kernels, and the Pegasos stage inside each shard
+    takes the resident path."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from repro.engine import maxmarg
+    from repro.engine.state import (ProtocolInstance, pack_instances_maxmarg,
+                                    shard_specs)
+
+    n_pad, d, B, width = 8192, 10, 128, 40
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    S = mesh.shape["data"]
+    shard = (np.zeros((n_pad, d), np.float32), np.ones(n_pad, np.int32))
+    data, state, k, _cap = pack_instances_maxmarg(
+        [ProtocolInstance([shard] * K_NODES, 0.05, "maxmarg")],
+        max_epochs=16, max_support=4)
+    dspec, sspec = shard_specs(data), shard_specs(state)
+
+    def shapes(tree, specs):
+        return jax.tree_util.tree_map(
+            lambda a, p: jax.ShapeDtypeStruct(
+                (B,) + a.shape[1:] if a.ndim else a.shape, a.dtype,
+                sharding=NamedSharding(mesh, p)), tree, specs)
+
+    lanes = NamedSharding(mesh, jax.sharding.PartitionSpec("data"))
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    jax.clear_caches()          # no trace cached with the CPU answer
+    ops.PEGASOS_PATH_LOG.clear()
+    try:
+        _full, sub = maxmarg._sharded_dispatches(
+            mesh, dspec, sspec, (k, 4, 2000, 3, 1e-3, True, True), True)
+        txt = sub.lower(
+            shapes(data, dspec), shapes(state, sspec),
+            jax.ShapeDtypeStruct((S * L,), jnp.int32, sharding=lanes),
+            jax.ShapeDtypeStruct((S,), jnp.int32, sharding=lanes),
+            trans_width=width, warm=True, per_node=True).compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert "tpu_custom_call" in txt
+    assert "pegasos_stage_batched" in txt
+    log = list(ops.PEGASOS_PATH_LOG)
+    assert log and all(b == L and path == "resident"
+                       for b, _n, _d, path in log), log
