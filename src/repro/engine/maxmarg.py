@@ -673,9 +673,12 @@ def run_instances(
     device mesh (requires ``compact=True``); ``donate``/``overlap`` opt the
     per-turn dispatches into buffer donation and the double-buffered host
     loop (mesh default: both on).  ``stats`` collects the hot loop's
-    counters (``hotloop.run_hot``).  Tracing: ``sweep.pack`` spans packing
-    and upload, ``sweep.collect`` the read-back of the results, and the
-    hot loop writes the per-turn ``sweep.*`` spans between them.
+    counters (``hotloop.run_hot``) and the upload's
+    (``state.pack_instances_maxmarg``).  Tracing: ``sweep.pack`` spans
+    packing and upload, holding ``sweep.assemble`` around the on-device
+    assembly of the batch; ``sweep.collect`` spans the read-back of the
+    results, and the hot loop writes the per-turn ``sweep.*`` spans
+    between them.
 
     Compile-key contract: ``max_epochs``, ``max_support``, ``steps``,
     ``stages``, ``k``, ``d``, ``per_node``, the kernel toggles, and the
@@ -699,7 +702,7 @@ def run_instances(
     with jax.profiler.TraceAnnotation("sweep.pack"):
         data, state0, k, _cap = pack_instances_maxmarg(
             instances, max_epochs=max_epochs, max_support=max_support,
-            mesh=mesh)
+            mesh=mesh, stats=stats)
     if warm or compact:
         final = run_hot(data, state0, k=k, max_turns=k * max_epochs,
                         max_support=max_support, steps=steps, stages=stages,

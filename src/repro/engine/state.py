@@ -22,12 +22,16 @@ See DESIGN.md §"Batched engine" for the capacity bound and padding rules.
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
+import functools
+import os
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core.comm import wire_bytes
 from repro.core.sampling import EPSILON_NET_C, epsilon_net_size
@@ -185,22 +189,24 @@ def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
 
+def _batch_spec(ndim: int) -> PartitionSpec:
+    """Axis 0 splits over the mesh's "data" axis; a scalar replicates."""
+    return PartitionSpec("data", *([None] * (ndim - 1))) if ndim \
+        else PartitionSpec()
+
+
 def shard_specs(tree):
     """The engine's one sharding rule as a PartitionSpec pytree: axis 0 of
     every batched leaf splits over the mesh's "data" axis, scalar leaves
     replicate.  Works on any engine pytree — :class:`EngineData`,
     :class:`ProtocolState`, :class:`MaxMargState`."""
-    from jax.sharding import PartitionSpec
-    return jax.tree_util.tree_map(
-        lambda a: PartitionSpec() if np.ndim(a) == 0
-        else PartitionSpec("data", *([None] * (np.ndim(a) - 1))), tree)
+    return jax.tree_util.tree_map(lambda a: _batch_spec(np.ndim(a)), tree)
 
 
 def device_put_sharded(tree, mesh):
     """Place an engine pytree on ``mesh`` under :func:`shard_specs` — host
     (numpy) leaves upload straight to their shards, so a packed sweep is
     *born sharded* rather than materialized on one device and resharded."""
-    from jax.sharding import NamedSharding
     return jax.tree_util.tree_map(
         lambda a, p: jax.device_put(a, NamedSharding(mesh, p)),
         tree, shard_specs(tree))
@@ -230,20 +236,155 @@ def maxmarg_transcript_capacity(k: int, max_epochs: int,
     return _round_up(max_epochs * (max_support + 2) * (k - 1) + 8, 8)
 
 
+_LANES = 128
+_TILE = 8 * _LANES      # one (8, 128) tile of 32-bit words
+# an upload buffer stays under glibc's largest dynamic mmap threshold
+# (32 MiB), so the next sweep reuses a freed buffer's pages instead of
+# faulting fresh ones in
+_CHUNK_BYTES = 16 << 20
+
+
+def _lane_buffer(arrays, dtype, rows: int):
+    """``arrays`` as lane-dense ``(rows, 128)`` pieces of ``dtype`` laid end
+    to end in one ``(len(arrays) * rows, 128)`` host buffer; words past an
+    array's end are zero (label 0, the inert label).  Also returns, for
+    each array, whether its words went in as they are (one block copy:
+    ``dtype``, C order, and a flat length of exactly ``rows * 128``) rather
+    than converted, gathered or zero-padded on the host."""
+    buf = np.empty((len(arrays), rows * _LANES), dtype)
+    as_is = []
+    for dst, a in zip(buf, arrays):
+        a = np.asarray(a)
+        as_is.append(a.dtype == dtype and a.flags.c_contiguous
+                     and a.size == dst.size)
+        dst[:a.size] = a.reshape(-1)
+        dst[a.size:] = 0
+    return buf.reshape(-1, _LANES), as_is
+
+
+@functools.partial(jax.jit, static_argnames=("k", "n_pad", "d", "rows"))
+def _assemble_slab(xs, ys, *, k: int, n_pad: int, d: int, rows: int):
+    """One device's slab of a MAXMARG sweep from the :func:`_lane_buffer`
+    buffers of its instances' shards (instance-major, node-minor): X
+    ``(rows, k, n_pad, d)`` f32 and y ``(rows, k, n_pad)`` i32.  Rows past
+    the buffers' instances are zero: born-done mesh padding.
+
+    Each X piece is turned point-minor on its own, ``(n_pad, d)`` to
+    ``(d, n_pad)``, before the stack: on a TPU the batch's layout keeps
+    points in lanes, and a d-minor ``(n_pad, d)`` intermediate pads d to
+    128 lanes, so turned one shard at a time it takes one shard's worth of
+    memory (4 MB at n_pad 8192) instead of the slab's (537 MB at 32 x 4
+    shards)."""
+    rx, ry = _round_up(n_pad * d, _TILE), _round_up(n_pad, _TILE)
+    px = [p for c in xs for p in c.reshape(-1, rx)]
+    pad = rows * k - len(px)
+    X = jnp.stack([p[:n_pad * d].reshape(n_pad, d).T for p in px]
+                  + [jnp.zeros((d, n_pad), jnp.float32)] * pad)
+    X = X.reshape(rows, k, d, n_pad)
+    y = jnp.concatenate([*ys, jnp.zeros((pad * ry // _LANES, _LANES),
+                                        jnp.int32)])
+    y = y.reshape(rows * k, ry)[:, :n_pad].reshape(rows, k, n_pad)
+    return jnp.swapaxes(X, 2, 3), y
+
+
+@functools.lru_cache(maxsize=32)
+def _zeros_program(leaves):
+    """One program that makes a zero array on the devices for each
+    ``(shape, dtype, sharding)`` of ``leaves``, placed by that sharding."""
+    return jax.jit(lambda: tuple(jnp.zeros(shape, dtype)
+                                 for shape, dtype, _ in leaves),
+                   out_shardings=tuple(sh for *_, sh in leaves))
+
+
+def _upload_maxmarg_slabs(instances, *, k, n_pad, d, B, mesh, stats):
+    """X ``(B, k, n_pad, d)`` and y ``(B, k, n_pad)`` of a MAXMARG sweep,
+    built on the devices.  Each device's instances' shards go up as
+    lane-dense :func:`_lane_buffer` buffers of at most ``_CHUNK_BYTES``,
+    one host thread a buffer filling it and putting it on its device, and
+    :func:`_assemble_slab` builds each device's slab there.  With ``mesh``
+    the slabs join under :func:`shard_specs`' sharding, device s holding
+    the rows ``NamedSharding(mesh, P("data"))`` gives it; without, the one
+    slab is on the default device.  No host relayout runs: an
+    ``(R, 128)`` f32/i32 buffer with R a multiple of 8 has the same bytes
+    row-major and in the chip's (8, 128) tiling."""
+    rows_x = _round_up(n_pad * d, _TILE) // _LANES
+    rows_y = _round_up(n_pad, _TILE) // _LANES
+    if mesh is None:
+        spans = [(None, 0, B)]
+    else:
+        lanes = NamedSharding(mesh, _batch_spec(1))
+        spans = [(dev, *idx[0].indices(B)[:2]) for dev, idx
+                 in lanes.addressable_devices_indices_map((B,)).items()]
+    # one task a buffer: (span, leaf, arrays, dtype, rows)
+    tasks = []
+    for i, (_dev, lo, hi) in enumerate(spans):
+        shards = [s for inst in instances[lo:hi] for s in inst.shards]
+        for leaf, dtype, rows in ((0, np.float32, rows_x),
+                                  (1, np.int32, rows_y)):
+            per = max(1, _CHUNK_BYTES // (rows * _LANES * 4))
+            tasks += [(i, leaf, [s[leaf] for s in shards[g:g + per]],
+                       dtype, rows) for g in range(0, len(shards), per)]
+
+    def upload(task):
+        i, _leaf, arrays, dtype, rows = task
+        buf, as_is = _lane_buffer(arrays, dtype, rows)
+        return jax.device_put(buf, spans[i][0]), as_is
+
+    with concurrent.futures.ThreadPoolExecutor(
+            max(1, min(len(tasks), os.cpu_count() or 1))) as pool:
+        done = list(pool.map(upload, tasks))
+    bufs = [([], []) for _ in spans]
+    as_is = [([], []) for _ in spans]
+    for (i, leaf, *_), (buf, flags) in zip(tasks, done):
+        bufs[i][leaf].append(buf)
+        as_is[i][leaf].extend(flags)
+    direct = sum(x and y for fx, fy in as_is for x, y in zip(fx, fy))
+    copied = sum(len(fx) for fx, _fy in as_is) - direct
+    if stats is not None:
+        stats["upload_shards_direct"] = \
+            stats.get("upload_shards_direct", 0) + direct
+        stats["upload_shards_copied"] = \
+            stats.get("upload_shards_copied", 0) + copied
+    with jax.profiler.TraceAnnotation("sweep.assemble", direct=direct,
+                                      copied=copied):
+        slabs = []
+        for (dev, lo, hi), (xs, ys) in zip(spans, bufs):
+            # a device of padding rows alone gets no buffers: its slab of
+            # zeros is made there all the same
+            with jax.default_device(dev):
+                slabs.append(_assemble_slab(xs, ys, k=k, n_pad=n_pad, d=d,
+                                            rows=hi - lo))
+    if mesh is None:
+        return slabs[0]
+    return tuple(
+        jax.make_array_from_single_device_arrays(
+            (B,) + part.shape[1:],
+            NamedSharding(mesh, _batch_spec(part.ndim)),
+            [slab[i] for slab in slabs])
+        for i, part in enumerate(slabs[0]))
+
+
 def pack_instances_maxmarg(
     instances: Sequence[ProtocolInstance],
     *,
     max_epochs: int,
     max_support: int,
     mesh=None,
+    stats: Optional[dict] = None,
 ) -> Tuple[EngineData, MaxMargState, int, int]:
     """Pad a MAXMARG sweep onto the engine's static shapes.
 
     Returns ``(data, state0, k, cap)``.  All instances must share the party
     count k and the dimension d (any d ≥ 2 — MAXMARG has no direction grid);
-    shard sizes may be ragged (label-0 padding).  With ``mesh`` the batch
-    pads to a multiple of the data-axis size with born-done dummy rows and
-    uploads born-sharded (:func:`device_put_sharded`).
+    shard sizes may be ragged (label-0 padding).  The shards go up as
+    lane-dense pieces and the batch is built on the devices
+    (:func:`_upload_maxmarg_slabs`); ``stats`` counts the shards whose
+    words went up as they are (``upload_shards_direct``) and those
+    converted, gathered or zero-padded on the host first
+    (``upload_shards_copied``).  With ``mesh`` the batch pads to a
+    multiple of the data-axis size with born-done dummy rows, every leaf is
+    born sharded under :func:`shard_specs`, and the zero leaves of the
+    state are made on the devices.
     """
     assert instances, "need at least one instance"
     ks = {len(inst.shards) for inst in instances}
@@ -257,25 +398,22 @@ def pack_instances_maxmarg(
                           for s in inst.shards), 8)
     cap = maxmarg_transcript_capacity(k, max_epochs, max_support)
 
-    X = np.zeros((B, k, n_max, d), np.float32)
-    y = np.zeros((B, k, n_max), np.int32)
     budget = np.zeros((B,), np.int32)
     for b, inst in enumerate(instances):
         n_total = 0
-        for j, (Xs, ys) in enumerate(inst.shards):
-            n = Xs.shape[0]
+        for Xs, ys in inst.shards:
             assert (np.abs(ys) == 1).all(), "labels must be +-1"
-            X[b, j, :n] = Xs
-            y[b, j, :n] = ys
-            n_total += n
+            n_total += Xs.shape[0]
         budget[b] = int(np.floor(inst.eps * n_total))
     done0 = np.zeros((B,), bool)
     done0[len(instances):] = True                    # born-done mesh padding
+    X, y = _upload_maxmarg_slabs(instances, k=k, n_pad=n_max, d=d, B=B,
+                                 mesh=mesh, stats=stats)
 
-    data = EngineData(X, y, budget)
-    # numpy zeros for the initial state: the leaves upload at the first
-    # dispatch like any jit input, without one eager device op per field
-    # (a dozen tiny dispatches of pure overhead per sweep otherwise)
+    # numpy zeros for the initial state: without a mesh the leaves upload
+    # at the first dispatch like any jit input, without one eager device op
+    # per field (a dozen tiny dispatches of pure overhead per sweep
+    # otherwise); with one, they are made on the devices in one program
     state0 = MaxMargState(
         wx=np.zeros((B, k, cap, d), np.float32),
         wy=np.zeros((B, k, cap), np.int32),
@@ -296,11 +434,17 @@ def pack_instances_maxmarg(
         comm=BatchCommLog(*(np.zeros((B,), np.int32)
                             for _ in BatchCommLog._fields)),
     )
-    if mesh is not None:
-        return (device_put_sharded(data, mesh),
-                device_put_sharded(state0, mesh), k, cap)
-    data = EngineData(jnp.asarray(X), jnp.asarray(y), jnp.asarray(budget))
-    return data, state0, k, cap
+    if mesh is None:
+        return EngineData(X, y, jnp.asarray(budget)), state0, k, cap
+    lanes = NamedSharding(mesh, _batch_spec(1))
+    leaves, tree = jax.tree_util.tree_flatten(state0)
+    zeros = _zeros_program(tuple(
+        (a.shape, a.dtype, NamedSharding(mesh, _batch_spec(a.ndim)))
+        for a in leaves))()
+    state0 = jax.tree_util.tree_unflatten(tree, zeros)._replace(
+        done=jax.device_put(done0, lanes))
+    return (EngineData(X, y, jax.device_put(budget, lanes)), state0, k,
+            cap)
 
 
 def transcript_capacity(k: int, max_epochs: int) -> int:

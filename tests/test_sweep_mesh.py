@@ -222,10 +222,15 @@ def test_sweep_spans(insts, warm_sweep, tmp_path):
     by = {}
     for sp in spans:
         by.setdefault(sp[0], []).append(sp)
-    assert set(by) == {"sweep.pack", "sweep.turn", "sweep.dispatch",
-                       "sweep.view", "sweep.collect"}
+    assert set(by) == {"sweep.pack", "sweep.assemble", "sweep.turn",
+                       "sweep.dispatch", "sweep.view", "sweep.collect"}
     assert len(by["sweep.pack"]) == len(by["sweep.collect"]) == 1
-    (_n, _s, pack_end, _a), = by["sweep.pack"]
+    (_n, pack_start, pack_end, _a), = by["sweep.pack"]
+    (_n, s, e, a), = by["sweep.assemble"]
+    assert pack_start <= s and e <= pack_end
+    assert a["direct"] + a["copied"] == len(insts) * K
+    assert (a["direct"], a["copied"]) == (stats["upload_shards_direct"],
+                                          stats["upload_shards_copied"])
     (_n, collect_start, _e, _a), = by["sweep.collect"]
     turns = by["sweep.turn"]
     assert len(turns) == len(by["sweep.dispatch"]) == stats["turns"]

@@ -216,3 +216,29 @@ def test_sharded_sub_turn_compiles(topo, one_chip, monkeypatch, L):
     log = list(ops.PEGASOS_PATH_LOG)
     assert log and all(b == L and path == "resident"
                        for b, _n, _d, path in log), log
+
+
+def test_sweep_slab_assembly_compiles(one_chip):
+    """The four-chip sweep's batch assembly on one chip at the cell's
+    per-chip shape (32 instances of k=4 shards, 8192 points a node, d=10):
+    the upload buffers of (640, 128) f32 and (64, 128) i32 pieces become
+    the engine's X and y, and its temporaries stay under one slab (no
+    lane-padded d-minor copy of the whole slab)."""
+    from repro.engine.state import _CHUNK_BYTES, _assemble_slab
+
+    L, n_pad, d = 32, 8192, 10
+
+    def buffers(rows, dtype):
+        per = _CHUNK_BYTES // (rows * 128 * 4)
+        return [_sds(one_chip, (min(per, L * K_NODES - i) * rows, 128), dtype)
+                for i in range(0, L * K_NODES, per)]
+
+    xs, ys = buffers(n_pad * d // 128, jnp.float32), buffers(64, jnp.int32)
+    assert len(xs) == 3 and len(ys) == 1
+    compiled = _assemble_slab.lower(xs, ys, k=K_NODES, n_pad=n_pad, d=d,
+                                    rows=L).compile()
+    X, y = compiled.out_info
+    assert (X.shape, X.dtype) == ((L, K_NODES, n_pad, d), jnp.float32)
+    assert (y.shape, y.dtype) == ((L, K_NODES, n_pad), jnp.int32)
+    slab = L * K_NODES * n_pad * d * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < slab
