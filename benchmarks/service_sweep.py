@@ -147,19 +147,18 @@ def _pool_cache_entries() -> int:
     """Total jit cache entries across every entry point a MEDIAN pool turn
     can hit (dispatch, admission scatter, corruption, supervision view,
     eviction mark)."""
-    fns = (median._hot_turn, session_pool._admit_rows,
-           session_pool._corrupt_median, session_pool._view_median,
+    fns = (hotloop.programs(median.FAMILY)[1], session_pool._admit_rows,
+           session_pool._corrupt, session_pool._view,
            session_pool._mark_done)
     return sum(f._cache_size() for f in fns)
 
 
 def _unified_cache_entries() -> int:
-    """Same census for a unified pool turn — ``_corrupt_unified`` /
-    ``_view_unified`` are aliases of the maxmarg jits (jit re-keys on the
-    pytree structure, and UnifiedState shares the leaf names they touch),
-    so counting the alias targets counts them."""
-    fns = (unified._hot_turn, session_pool._admit_rows,
-           session_pool._corrupt_maxmarg, session_pool._view_maxmarg,
+    """Same census for a unified pool turn (``_corrupt`` / ``_view`` are
+    shared by every family: their jits re-key on the pytree structure and
+    the separator leaves)."""
+    fns = (hotloop.programs(unified.FAMILY)[1], session_pool._admit_rows,
+           session_pool._corrupt, session_pool._view,
            session_pool._mark_done)
     return sum(f._cache_size() for f in fns)
 

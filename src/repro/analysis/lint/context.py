@@ -322,6 +322,8 @@ class FileContext:
         "lax.fori_loop",
     }
 
+    _FAMILY_RECORDS = {"repro.engine.hotloop.Family"}
+
     def _decorator_statics(self, fn: ast.FunctionDef) -> Optional[Set[str]]:
         """If ``fn`` is traced via decorator, return its static param names
         (None → not traced via decorator)."""
@@ -380,6 +382,18 @@ class FileContext:
                 if prev is not None and statics is not None:
                     statics = set(prev) | statics
                 traced[b.wrapped] = statics
+        # (c) protocol family records: the hot loop's program factory jits
+        # ``Family(step=fn, statics=...)``'s step with exactly those statics
+        for node in ast.walk(self.tree):
+            if (not isinstance(node, ast.Call)
+                    or self.call_canonical(node) not in self._FAMILY_RECORDS):
+                continue
+            kw = {k.arg: k.value for k in node.keywords}
+            fn = kw.get("step")
+            if isinstance(fn, ast.Name) and fn.id in self.functions:
+                got = (self.resolve_str_tuple(kw["statics"])
+                       if "statics" in kw else None)
+                traced[fn.id] = set(got) if got is not None else None
         # Callbacks handed positionally to lax control flow / shard_map /
         # vmap are resolved slot-aware by R005 itself.
         return traced

@@ -9,11 +9,11 @@ here we track it as dataflow over the function body.
 Donating callees are resolved two ways:
 
 * precisely, from ``X = jax.jit(fn, donate_arg...)`` bindings in the same
-  module (including one alias hop, e.g. ``step_d = _step_jit_don if
-  donate else _step_jit`` — donating if ANY reaching binding donates);
+  module (including one alias hop, e.g. ``step_d = step_don if donate
+  else step_plain`` — donating if ANY reaching binding donates);
 * by configured name pattern (``donating_patterns``) for factory-made
   dispatches whose jit call is out of view (``full_j``/``sub_j`` from
-  ``_sharded_dispatches``); there the donated argument is any bare name
+  ``hotloop.programs``); there the donated argument is any bare name
   argument listed in ``donated_arg_names``.
 """
 

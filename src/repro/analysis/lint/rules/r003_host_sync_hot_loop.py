@@ -1,9 +1,9 @@
 """R003 host-sync-in-hot-loop.
 
 The hot loops overlap dispatch with host work through exactly one
-blessed transfer: the packed ``(3, B)`` i32 host view (``host_view`` /
-``_view_median`` / ``_view_maxmarg``).  Any other device→host sync inside
-a turn loop — ``.item()``, ``.tolist()``, ``.block_until_ready()``,
+blessed transfer: the packed ``(3, B)`` i32 host view (``host_view``,
+and the pool's ``(5, W)`` supervision ``_view``).  Any other device→host
+sync inside a turn loop — ``.item()``, ``.tolist()``, ``.block_until_ready()``,
 ``np.asarray`` on device values, ``jax.device_get``, ``float()``/``int()``
 on device leaves — serializes the pipeline and silently destroys the
 double-buffered overlap PR 6 measured.

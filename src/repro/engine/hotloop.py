@@ -28,8 +28,8 @@ transcript-driven selector) rides the identical code path:
   the per-turn sub-batch index is built *per shard* (``balanced_index``):
   the live set splits into S local slices padded to a common multiple of
   ``BATCH_MULT``, so every device runs the same shapes and none idles while
-  another runs live rows; the selector's sharded dispatches map them over a
-  1-D ("data",) mesh;
+  another runs live rows; the sharded programs (:func:`programs`) map them
+  over a 1-D ("data",) mesh;
 * **double buffering** (``overlap=True``) — turn t+1 is dispatched from the
   one-turn-*stale* host view before the host blocks on turn t's view
   decode, overlapping host decision logic with device compute.  Sound
@@ -38,16 +38,19 @@ transcript-driven selector) rides the identical code path:
   ``width_growth`` bound covers the true fill; at most one wasted all-done
   masked dispatch runs at termination.
 
-The selector supplies three callables (see :func:`run_hot`); everything it
-must guarantee about padding rows is the engine's standing label-0
-convention plus a ``pad_fix`` that marks gathered out-of-range rows inert
-(``done=True``, and for warm selectors: carries trusted, so zero-data pad
-rows latch instantly and can never force solver work the live rows don't
-need).
+Each selector describes itself once, as a :class:`Family` record; every
+turn program and dispatch is built from it here (:func:`programs`,
+:func:`run_family`).  All a family must guarantee about padding rows is
+the engine's standing label-0 convention plus a ``pad_fix`` that marks
+gathered out-of-range rows inert (``done=True``, and for warm selectors:
+carries trusted, so zero-data pad rows latch instantly and can never force
+solver work the live rows don't need).
 """
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import time
 from typing import Callable, List, Optional, Tuple
 
@@ -55,7 +58,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.engine.state import _round_up, shard_specs  # noqa: F401 (re-export)
+from repro.engine.state import _round_up, device_put_sharded, shard_specs
 
 BATCH_MULT = 4   # live batch rounds up to this (compile-cache granularity)
 WIDTH_MULT = 8   # live transcript width rounds up to this
@@ -130,9 +133,9 @@ def put_instances(full, sub, idx):
 def gathered_turn(step_fn, pad_fix, data, state, idx, n_act):
     """One compacted turn as gather → pad-fix → step → scatter.
 
-    The selector wraps this in its own ``jax.jit`` (its static options
-    differ), so the whole turn stays one device computation: eager per-leaf
-    gathers/scatters cost more than the step they wrap on CPU.  ``idx`` is
+    :func:`programs` wraps this in one ``jax.jit``, so the whole turn stays
+    one device computation: eager per-leaf gathers/scatters cost more than
+    the step they wrap on CPU.  ``idx`` is
     (n_pad,) i32 with the live rows in front and out-of-range tail indices;
     ``n_act`` is the traced live count; ``pad_fix(sub_state, pad_row)``
     marks the gathered tail rows inert for this selector.
@@ -143,6 +146,128 @@ def gathered_turn(step_fn, pad_fix, data, state, idx, n_act):
     sub = pad_fix(sub, pad_row)
     sub = step_fn(sub_data, sub)
     return put_instances(state, sub, idx)
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class Family:
+    """One protocol family, described once: what the hot loop and the
+    session pool need of it, and nothing a caller sets.
+
+    ``step(data, *consts, state, **opts, **turn)`` advances every row by
+    one turn; ``statics`` are its static keywords, among them those of
+    ``first_turn``/``trans_width``/``warm`` that the loop sets per turn
+    (:meth:`turn_kw`).  ``takes_grid`` puts the direction grid V
+    between ``data`` and ``state`` as an operand no gather touches.
+    ``host_view(state, ci, opts)`` returns the packed (3, B) per-turn view;
+    ``width_slack(opts)`` widens each compacted read past the turn-start
+    fill and ``width_growth(opts)`` bounds one turn's fill growth (the
+    stale-view slack of the double-buffered loop).
+
+    For the session pool: ``capacity(cfg)`` is the transcript rows a
+    ``PoolConfig`` pins, ``pool_opts(cfg, solver_kernel)`` the step options
+    of its pinned turn, ``fresh_state(A, cfg, live, batch)`` the host rows
+    of one admission wave, and ``sep`` names the (normal, offset) separator
+    leaves the supervision view, the corruption wave and eviction read.
+    """
+
+    step: Callable
+    statics: Tuple[str, ...]
+    pad_fix: Callable
+    host_view: Callable
+    width_slack: Callable
+    width_growth: Callable
+    takes_grid: bool
+    capacity: Callable
+    pool_opts: Callable
+    fresh_state: Callable
+    sep: Tuple[str, str]
+
+    @property
+    def turn_statics(self) -> Tuple[str, ...]:
+        """The static keywords the loop sets per turn."""
+        return tuple(n for n in ("first_turn", "trans_width", "warm")
+                     if n in self.statics)
+
+    def turn_kw(self, *, first_turn: bool, trans_width: Optional[int],
+                warm: bool) -> dict:
+        """The per-turn static keywords this family's step takes."""
+        kw = dict(first_turn=first_turn, trans_width=trans_width, warm=warm)
+        return {n: kw[n] for n in self.turn_statics}
+
+
+def programs(fam: Family, donate: bool = False, mesh=None, dspec=None,
+             sspec=None, opts: Tuple = ()):
+    """The family's (full-batch, sub-batch) turn programs, built once per
+    variant so that the pool and every sweep share one jit object each.
+
+    Without a mesh they are ``jit(step)`` (``jit_step``) and
+    ``jit(_hot_turn_impl)``, the gathered turn (:func:`gathered_turn`),
+    with ``fam.statics`` static: options arrive per call, and a
+    non-static one (MAXMARG's ``lam0``) stays a traced operand.  With a
+    1-D ("data",) ``mesh`` they are ``_sharded_full_turn`` /
+    ``_sharded_sub_turn``, jitted ``shard_map``s over the leading B axis
+    (``dspec``/``sspec`` the data and state specs): each shard runs the
+    unmodified single-device program on its B/S slice, no collective
+    crosses shards (decisions are per instance), the options ``opts`` (a
+    sorted item tuple) are baked in and only ``fam.turn_statics`` stay
+    static.  ``donate`` donates ``state``: the scatter-back then reuses the
+    transcript buffers in place.  The function names are the programs'
+    names in a device trace.
+    """
+    if mesh is None:
+        dspec = sspec = None
+        opts = ()
+    return _programs(fam, bool(donate), mesh, dspec, sspec, opts)
+
+
+@functools.lru_cache(maxsize=None)
+def _programs(fam, donate, mesh, dspec, sspec, opts):
+    nc = int(fam.takes_grid)
+    dn = (1 + nc,) if donate else ()
+    if mesh is None:
+        def _hot_turn_impl(data, *args, **kw):
+            *consts, state, idx, n_act = args
+            step = functools.partial(fam.step, **kw)
+            return gathered_turn(lambda d, s: step(d, *consts, s),
+                                 fam.pad_fix, data, state, idx, n_act)
+
+        full_j = jax.jit(fam.step, static_argnames=fam.statics,
+                         donate_argnums=dn)
+        sub_j = jax.jit(_hot_turn_impl, static_argnames=fam.statics,
+                        donate_argnums=dn)
+        return full_j, sub_j
+
+    from jax.experimental.shard_map import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    step = functools.partial(fam.step, **dict(opts))
+    cspec = (P(None, None),) * nc
+
+    def _sharded_full_turn(data, *args, **turn):
+        def body(d, *a):
+            return step(d, *a, **turn)
+        return shard_map(body, mesh=mesh, in_specs=(dspec, *cspec, sspec),
+                         out_specs=sspec, check_rep=False)(data, *args)
+
+    def _sharded_sub_turn(data, *args, **turn):
+        # idx is the (S·L,) per-shard block from balanced_index and n_act
+        # the (S,) per-shard live counts — each shard sees its (L,) local
+        # slice and (1,) count and runs the plain gathered turn
+        def body(d, *a):
+            *consts, s, ix, na = a
+            return gathered_turn(
+                lambda sd, ss: step(sd, *consts, ss, **turn),
+                fam.pad_fix, d, s, ix, na[0])
+        return shard_map(body, mesh=mesh,
+                         in_specs=(dspec, *cspec, sspec, P("data"),
+                                   P("data")),
+                         out_specs=sspec, check_rep=False)(data, *args)
+
+    full_j = jax.jit(_sharded_full_turn, static_argnames=fam.turn_statics,
+                     donate_argnums=dn)
+    sub_j = jax.jit(_sharded_sub_turn, static_argnames=fam.turn_statics,
+                    donate_argnums=dn)
+    return full_j, sub_j
 
 
 def shard_skew(counts: np.ndarray) -> float:
@@ -391,3 +516,77 @@ def run_hot(
         else:
             view = decode(vh)
     return state
+
+
+def run_family(
+    fam: Family,
+    data,
+    consts: tuple,
+    state,
+    opts: dict,
+    *,
+    max_turns: int,
+    warm: bool = False,
+    compact: bool = True,
+    mesh: Optional[jax.sharding.Mesh] = None,
+    donate: Optional[bool] = None,
+    overlap: Optional[bool] = None,
+    width_policy: str = "linear",
+    stats: Optional[dict] = None,
+):
+    """One family's sweep: place the batch, wrap the family's
+    :func:`programs` as the loop's dispatches, and run :func:`run_hot`.
+
+    ``consts`` are the operands between ``data`` and ``state`` (the
+    direction grid where ``fam.takes_grid``) and ``opts`` the step's
+    options (``opts["k"]`` the party count).  ``mesh`` (a 1-D ("data",)
+    mesh, ``launch.mesh.make_data_mesh``) shards every dispatch over the
+    leading B axis: B must be a multiple of the axis size (the packers'
+    ``mesh=`` pads with born-done dummies), the batch is placed shard by
+    shard, and ``donate``/``overlap`` default on.  Off the mesh both
+    default off, keeping the path the unchanged oracle; ``donate=True``
+    uploads the state first, since donating host numpy buffers is
+    silently ignored.
+    """
+    cap = int(state.wx.shape[2])
+    shards = None
+    call_opts = opts
+    if mesh is None:
+        donate, overlap = bool(donate), bool(overlap)
+        if donate:
+            state = jax.tree_util.tree_map(jnp.asarray, state)
+        full_j, sub_j = programs(fam, donate)
+    else:
+        if not compact:
+            raise ValueError("sharded sweeps require the compacted hot path")
+        shards = int(mesh.shape["data"])
+        B = int(state.done.shape[0])
+        if B % shards:
+            raise ValueError(
+                f"B={B} not divisible by mesh axis {shards}; pack with mesh=")
+        donate = True if donate is None else donate
+        overlap = True if overlap is None else overlap
+        data = device_put_sharded(data, mesh)
+        state = device_put_sharded(state, mesh)
+        consts = tuple(jnp.asarray(c, jnp.float32) for c in consts)
+        full_j, sub_j = programs(fam, donate, mesh, shard_specs(data),
+                                 shard_specs(state),
+                                 tuple(sorted(opts.items())))
+        call_opts = {}
+
+    def dispatch_full(s, *, t, width, use_warm):
+        return full_j(data, *consts, s, **call_opts, **fam.turn_kw(
+            first_turn=(t == 0), trans_width=width, warm=use_warm))
+
+    def dispatch_sub(s, idx, n_act, *, t, width, use_warm):
+        return sub_j(data, *consts, s, idx, n_act, **call_opts, **fam.turn_kw(
+            first_turn=(t == 0), trans_width=width, warm=use_warm))
+
+    return run_hot(state, k=opts["k"], max_turns=max_turns, cap=cap,
+                   host_view=lambda s, ci: fam.host_view(s, ci, opts),
+                   dispatch_full=dispatch_full, dispatch_sub=dispatch_sub,
+                   warm=warm, compact=compact,
+                   width_slack=fam.width_slack(opts),
+                   width_growth=fam.width_growth(opts),
+                   width_policy=width_policy, overlap=overlap,
+                   shards=shards, stats=stats)

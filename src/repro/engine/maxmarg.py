@@ -68,9 +68,9 @@ from repro.engine.state import (
     EngineData,
     MaxMargState,
     ProtocolInstance,
-    device_put_sharded,
+    blank_maxmarg_state,
+    maxmarg_transcript_capacity,
     pack_instances_maxmarg,
-    shard_specs,
 )
 from repro.kernels import ops, ref
 
@@ -371,13 +371,6 @@ def run_compiled(
 _STEP_STATICS = ("k", "max_support", "steps", "stages", "trans_width",
                  "warm", "per_node", "fused_kernel", "solver_kernel")
 
-_step_jit = jax.jit(step, static_argnames=_STEP_STATICS)
-# the donated variant: the per-turn output reuses the input state's buffers
-# in place (jax invalidates the donated handle — run_hot keeps a strict
-# single-consumer chain, see hotloop.run_hot's donation contract)
-_step_jit_don = jax.jit(step, static_argnames=_STEP_STATICS,
-                        donate_argnames=("state",))
-
 
 def _pad_fix(sub: MaxMargState, pad_row: jnp.ndarray) -> MaxMargState:
     """Mark gathered out-of-range rows inert: done=True masks them out of
@@ -389,94 +382,6 @@ def _pad_fix(sub: MaxMargState, pad_row: jnp.ndarray) -> MaxMargState:
                         h_valid=sub.h_valid | pad_row,
                         c_valid=sub.c_valid | pad_row[:, None],
                         warm_node=sub.warm_node | pad_row[:, None])
-
-
-def _hot_turn_impl(
-    data: EngineData,
-    state: MaxMargState,
-    idx: jnp.ndarray,       # (n_pad,) i32 — active rows, tail = B (dropped)
-    n_act: jnp.ndarray,     # () i32 — live prefix of idx
-    *,
-    k: int,
-    max_support: int,
-    steps: int,
-    stages: int,
-    lam0: float,
-    trans_width: int,
-    warm: bool,
-    per_node: bool,
-    fused_kernel: bool,
-    solver_kernel: Optional[bool] = None,
-) -> MaxMargState:
-    """One compacted turn as a single dispatch: gather the active instances,
-    advance them by one ``step`` at the compacted transcript width, scatter
-    the results back (``hotloop.gathered_turn`` — fusing the gather/scatter
-    into the turn's jit keeps the host loop at one device computation per
-    turn; eager per-leaf scatters cost more than the refit they wrap on
-    CPU)."""
-    step_fn = functools.partial(
-        step, k=k, max_support=max_support, steps=steps, stages=stages,
-        lam0=lam0, trans_width=trans_width, warm=warm, per_node=per_node,
-        fused_kernel=fused_kernel, solver_kernel=solver_kernel)
-    return hotloop.gathered_turn(step_fn, _pad_fix, data, state, idx, n_act)
-
-
-_hot_turn = jax.jit(_hot_turn_impl, static_argnames=_STEP_STATICS)
-# donated: the scatter-back lands in the input buffers instead of copying
-# the full (B, k, cap, …) transcript state every tail turn
-_hot_turn_don = jax.jit(_hot_turn_impl, static_argnames=_STEP_STATICS,
-                        donate_argnames=("state",))
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_dispatches(mesh, dspec, sspec, opts, donate):
-    """Build (and cache per mesh/spec/static-variant) the sharded per-turn
-    dispatches: jitted ``shard_map``s of the full-batch step and of the
-    gathered sub-batch turn over the ("data",) mesh.  Everything inside a
-    shard is the unmodified single-device program on the local B/S slice —
-    MAXMARG decisions are per-instance, so no cross-shard collective exists.
-    ``check_rep=False``: every leaf (including the per-instance turn
-    counter) shards over the batch axis; nothing is replicated."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    k, max_support, steps, stages, lam0, fused_kernel, solver_kernel = opts
-
-    # the names become the programs' names in a device trace
-    # (``jit__sharded_full_turn`` / ``jit__sharded_sub_turn``)
-    def _sharded_full_turn(data, state, *, trans_width, warm, per_node):
-        def body(d, s):
-            return step(d, s, k=k, max_support=max_support, steps=steps,
-                        stages=stages, lam0=lam0, trans_width=trans_width,
-                        warm=warm, per_node=per_node,
-                        fused_kernel=fused_kernel,
-                        solver_kernel=solver_kernel)
-        return shard_map(body, mesh=mesh, in_specs=(dspec, sspec),
-                         out_specs=sspec, check_rep=False)(data, state)
-
-    def _sharded_sub_turn(data, state, idx, n_act, *, trans_width, warm,
-                          per_node):
-        # idx is the (S·L,) per-shard block from hotloop.balanced_index and
-        # n_act the (S,) per-shard live counts — each shard sees its (L,)
-        # local slice and (1,) count and runs the plain gathered turn
-        def body(d, s, ix, na):
-            step_fn = functools.partial(
-                step, k=k, max_support=max_support, steps=steps,
-                stages=stages, lam0=lam0, trans_width=trans_width,
-                warm=warm, per_node=per_node, fused_kernel=fused_kernel,
-                solver_kernel=solver_kernel)
-            return hotloop.gathered_turn(step_fn, _pad_fix, d, s, ix, na[0])
-        return shard_map(body, mesh=mesh,
-                         in_specs=(dspec, sspec, P("data"), P("data")),
-                         out_specs=sspec, check_rep=False)(
-                             data, state, idx, n_act)
-
-    statics = ("trans_width", "warm", "per_node")
-    dn = (1,) if donate else ()
-    return (jax.jit(_sharded_full_turn, static_argnames=statics,
-                    donate_argnums=dn),
-            jax.jit(_sharded_sub_turn, static_argnames=statics,
-                    donate_argnums=dn))
 
 
 @functools.partial(jax.jit, static_argnames=("per_node",))
@@ -501,6 +406,32 @@ def _host_view(state: MaxMargState, ci: jnp.ndarray, *,
                       fills])
 
 
+def _pool_opts(cfg, solver_kernel) -> dict:
+    """The step options of a pool's pinned turn: cold refits, no carry."""
+    return dict(k=cfg.k, max_support=cfg.max_support, steps=cfg.svm_steps,
+                stages=cfg.svm_stages, lam0=cfg.lam0, per_node=False,
+                fused_kernel=False, solver_kernel=solver_kernel)
+
+
+FAMILY = hotloop.Family(
+    step=step, statics=_STEP_STATICS, pad_fix=_pad_fix,
+    host_view=lambda state, ci, opts: _host_view(
+        state, ci, per_node=opts["per_node"]),
+    width_slack=lambda opts: 0,
+    # one turn's worst transcript growth: the S broadcast on a receiving
+    # node vs the ≤2-row replies from each of k−1 peers on the coordinator
+    width_growth=lambda opts: max(opts["max_support"],
+                                  VIOL_SHIP * (opts["k"] - 1)),
+    takes_grid=False,
+    capacity=lambda cfg: maxmarg_transcript_capacity(
+        cfg.k, cfg.max_epochs, cfg.max_support),
+    pool_opts=_pool_opts,
+    fresh_state=lambda A, cfg, live, batch: blank_maxmarg_state(
+        A, cfg.k, cfg.cap, cfg.d, np.arange(A) >= live),
+    sep=("h_w", "h_b"),
+)
+
+
 def run_hot(
     data: EngineData,
     state: MaxMargState,
@@ -522,7 +453,7 @@ def run_hot(
     stats: Optional[dict] = None,
 ) -> MaxMargState:
     """The MAXMARG sweep as a host-driven turn loop over the jitted ``step``
-    (the shared machinery in :mod:`repro.engine.hotloop`).
+    (:func:`repro.engine.hotloop.run_family` over :data:`FAMILY`).
 
     Relative to ``run_compiled`` (one while_loop at worst-case shapes) this
     trades one dispatch per *turn* — protocol sweeps converge in a few
@@ -550,88 +481,23 @@ def run_hot(
     transcript-determined optimum (tests/test_maxmarg_warm.py pins comm/
     rounds/convergence and the canonicalized separator across both paths).
 
-    ``mesh`` (a 1-D ("data",) mesh, ``launch.mesh.make_data_mesh``) routes
-    every dispatch through ``shard_map`` over the leading B axis — B must
-    be a multiple of the axis size (``pack_instances_maxmarg(..., mesh=``
-    pads with born-done dummies) and sub-batch turns come shard-balanced
-    from ``hotloop.balanced_index``.  ``donate``/``overlap`` default on
-    there (in-place scatter-back + double-buffered host loop; the
-    stale-view width grows by the worst one-turn transcript growth:
-    ``max(max_support, VIOL_SHIP·(k−1))`` — the S broadcast on a receiving
-    node vs the ≤2-row replies from each of k−1 peers on the coordinator).
+    ``mesh`` shards every dispatch over the leading B axis, with
+    ``donate``/``overlap`` on by default (``hotloop.run_family``).
     MAXMARG decisions are per-instance, so sharding itself is exact; the
     stale warm-gate under ``overlap`` may make different — equally valid —
     polish-skip choices, decision-preserving like the warm gate itself.
-    Single-device defaults keep this path the unchanged oracle;
-    ``donate=True``/``overlap=True`` opt in.
     """
-    B = int(state.done.shape[0])
-    cap = int(state.wx.shape[2])
     # carry bookkeeping must run on *every* turn of a warm per-node run
     # (including turns whose polish dispatch is skipped) but on none of a
     # cold or single-carry run, so the tracking flag is run-level, not
     # per-dispatch
-    track = per_node and warm
     opts = dict(k=k, max_support=max_support, steps=steps, stages=stages,
-                lam0=lam0, per_node=track, fused_kernel=fused_kernel,
-                solver_kernel=solver_kernel)
-    width_growth = max(max_support, VIOL_SHIP * (k - 1))
-
-    def host_view(s, ci):
-        return _host_view(s, ci, per_node=track)
-
-    if mesh is not None:
-        if not compact:
-            raise ValueError("sharded sweeps require the compacted hot path")
-        S = int(mesh.shape["data"])
-        if B % S:
-            raise ValueError(
-                f"B={B} not divisible by mesh axis {S}; pack with mesh=")
-        donate = True if donate is None else donate
-        overlap = True if overlap is None else overlap
-        data = device_put_sharded(data, mesh)
-        state = device_put_sharded(state, mesh)
-        full_j, sub_j = _sharded_dispatches(
-            mesh, shard_specs(data), shard_specs(state),
-            (k, max_support, steps, stages, lam0, fused_kernel,
-             solver_kernel), donate)
-
-        def dispatch_full(s, *, t, width, use_warm):
-            return full_j(data, s, trans_width=width, warm=use_warm,
-                          per_node=track)
-
-        def dispatch_sub(s, idx, n_act, *, t, width, use_warm):
-            return sub_j(data, s, idx, n_act, trans_width=width,
-                         warm=use_warm, per_node=track)
-
-        return hotloop.run_hot(state, k=k, max_turns=max_turns, cap=cap,
-                               host_view=host_view,
-                               dispatch_full=dispatch_full,
-                               dispatch_sub=dispatch_sub, warm=warm,
-                               compact=True, width_growth=width_growth,
-                               overlap=overlap, shards=S, stats=stats)
-
-    donate = bool(donate)
-    overlap = bool(overlap)
-    if donate:
-        # donating host numpy buffers is silently ignored — upload first so
-        # the in-place scatter actually engages
-        state = jax.tree_util.tree_map(jnp.asarray, state)
-    step_d = _step_jit_don if donate else _step_jit
-    turn_d = _hot_turn_don if donate else _hot_turn
-
-    def dispatch_full(s, *, t, width, use_warm):
-        return step_d(data, s, trans_width=width, warm=use_warm, **opts)
-
-    def dispatch_sub(s, idx, n_act, *, t, width, use_warm):
-        return turn_d(data, s, idx, n_act, trans_width=width,
-                      warm=use_warm, **opts)
-
-    return hotloop.run_hot(state, k=k, max_turns=max_turns, cap=cap,
-                           host_view=host_view, dispatch_full=dispatch_full,
-                           dispatch_sub=dispatch_sub, warm=warm,
-                           compact=compact, width_growth=width_growth,
-                           overlap=overlap, stats=stats)
+                lam0=lam0, per_node=per_node and warm,
+                fused_kernel=fused_kernel, solver_kernel=solver_kernel)
+    return hotloop.run_family(
+        FAMILY, data, (), state, opts, max_turns=max_turns, warm=warm,
+        compact=compact, mesh=mesh, donate=donate, overlap=overlap,
+        stats=stats)
 
 
 def run_instances(

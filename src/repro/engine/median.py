@@ -51,13 +51,12 @@ from jax import lax
 
 from repro.engine import hotloop
 from repro.engine.state import (
-    BatchCommLog,
     EngineData,
     ProtocolInstance,
     ProtocolState,
-    device_put_sharded,
+    blank_protocol_state,
     pack_instances,
-    shard_specs,
+    transcript_capacity,
 )
 
 _INF = jnp.inf
@@ -450,13 +449,6 @@ def run_compiled(
 _STEP_STATICS = ("k", "first_turn", "cut_kernel", "extremes_kernel",
                  "trans_width")
 
-_step_jit = jax.jit(step, static_argnames=_STEP_STATICS)
-# the donated variant: the per-turn output state reuses the input state's
-# buffers in place (jax invalidates the donated handle — run_hot keeps a
-# strict single-consumer chain, see hotloop.run_hot's donation contract)
-_step_jit_don = jax.jit(step, static_argnames=_STEP_STATICS,
-                        donate_argnames=("state",))
-
 
 def _pad_fix(sub: ProtocolState, pad_row: jnp.ndarray) -> ProtocolState:
     """Mark gathered out-of-range rows inert.  done=True masks them out of
@@ -464,84 +456,6 @@ def _pad_fix(sub: ProtocolState, pad_row: jnp.ndarray) -> ProtocolState:
     harmless under the label-0 convention (no valid rows ⇒ every masked
     reduction hits its identity) and the scatter drops them anyway."""
     return sub._replace(done=sub.done | pad_row)
-
-
-def _hot_turn_impl(
-    data: EngineData,
-    V: jnp.ndarray,
-    state: ProtocolState,
-    idx: jnp.ndarray,       # (n_pad,) i32 — active rows, tail = B (dropped)
-    n_act: jnp.ndarray,     # () i32 — live prefix of idx
-    *,
-    k: int,
-    first_turn: bool,
-    cut_kernel: bool,
-    extremes_kernel: bool,
-    trans_width: int,
-) -> ProtocolState:
-    """One compacted MEDIAN turn as a single dispatch (gather → step →
-    scatter fused, ``hotloop.gathered_turn``); V is shared across the batch
-    and passes through ungathered."""
-    step_fn = functools.partial(
-        step, k=k, first_turn=first_turn, cut_kernel=cut_kernel,
-        extremes_kernel=extremes_kernel, trans_width=trans_width)
-    return hotloop.gathered_turn(
-        lambda sub_data, sub: step_fn(sub_data, V, sub),
-        _pad_fix, data, state, idx, n_act)
-
-
-_hot_turn = jax.jit(_hot_turn_impl, static_argnames=_STEP_STATICS)
-# donated: the scatter-back lands in the input buffers instead of copying
-# the full (B, k, cap, …) transcript state every tail turn
-_hot_turn_don = jax.jit(_hot_turn_impl, static_argnames=_STEP_STATICS,
-                        donate_argnames=("state",))
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_dispatches(mesh, dspec, sspec, opts, donate):
-    """Build (and cache per mesh/spec/static-variant) the sharded per-turn
-    dispatches: jitted ``shard_map``s of the full-batch step and of the
-    gathered sub-batch turn over the ("data",) mesh.  Everything inside a
-    shard is the unmodified single-device program on the local B/S slice —
-    MEDIAN decisions are per-instance, so no cross-shard collective exists
-    and the sharded sweep is bit-exact against the single-device hot path.
-    ``check_rep=False``: every leaf (including the per-instance turn
-    counter) shards over the batch axis; nothing is replicated."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    k, cut_kernel, extremes_kernel = opts
-    vspec = P(None, None)
-
-    def full(data, V, state, *, first_turn, trans_width):
-        def body(d, v, s):
-            return step(d, v, s, k=k, first_turn=first_turn,
-                        cut_kernel=cut_kernel,
-                        extremes_kernel=extremes_kernel,
-                        trans_width=trans_width)
-        return shard_map(body, mesh=mesh, in_specs=(dspec, vspec, sspec),
-                         out_specs=sspec, check_rep=False)(data, V, state)
-
-    def sub(data, V, state, idx, n_act, *, first_turn, trans_width):
-        # idx is the (S·L,) per-shard block from hotloop.balanced_index and
-        # n_act the (S,) per-shard live counts — each shard sees its (L,)
-        # local slice and (1,) count and runs the plain gathered turn
-        def body(d, v, s, ix, na):
-            step_fn = functools.partial(
-                step, k=k, first_turn=first_turn, cut_kernel=cut_kernel,
-                extremes_kernel=extremes_kernel, trans_width=trans_width)
-            return hotloop.gathered_turn(
-                lambda sub_data, sub_s: step_fn(sub_data, v, sub_s),
-                _pad_fix, d, s, ix, na[0])
-        return shard_map(body, mesh=mesh,
-                         in_specs=(dspec, vspec, sspec, P("data"), P("data")),
-                         out_specs=sspec, check_rep=False)(
-                             data, V, state, idx, n_act)
-
-    statics = ("first_turn", "trans_width")
-    dn = (2,) if donate else ()
-    return (jax.jit(full, static_argnames=statics, donate_argnums=dn),
-            jax.jit(sub, static_argnames=statics, donate_argnums=dn))
 
 
 @jax.jit
@@ -554,6 +468,23 @@ def _host_view(state: ProtocolState, ci: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([state.done.astype(jnp.int32),
                       jnp.zeros_like(state.done, jnp.int32),
                       jnp.max(state.w_fill, axis=1)])
+
+
+FAMILY = hotloop.Family(
+    step=step, statics=_STEP_STATICS, pad_fix=_pad_fix,
+    host_view=lambda state, ci, opts: _host_view(state, ci),
+    width_slack=lambda opts: WIDTH_SLACK,
+    # one turn's worst fill growth: the S block, the ≤2 reply rows from
+    # each of k-1 peers, and the pivot pair
+    width_growth=lambda opts: 2 * opts["k"] + 2,
+    takes_grid=True,
+    capacity=lambda cfg: transcript_capacity(cfg.k, cfg.max_epochs),
+    pool_opts=lambda cfg, solver_kernel: dict(
+        k=cfg.k, cut_kernel=False, extremes_kernel=False),
+    fresh_state=lambda A, cfg, live, batch: blank_protocol_state(
+        A, cfg.k, cfg.cap, cfg.n_angles, np.arange(A) >= live),
+    sep=("h_v", "h_t"),
+)
 
 
 def run_hot(
@@ -572,8 +503,7 @@ def run_hot(
     stats: Optional[dict] = None,
 ) -> ProtocolState:
     """The MEDIAN sweep as a host-driven turn loop over the jitted ``step``
-    (the shared machinery in :mod:`repro.engine.hotloop`, mirroring
-    ``maxmarg.run_hot``).
+    (:func:`repro.engine.hotloop.run_family` over :data:`FAMILY`).
 
     MEDIAN transcripts are mostly empty early — every turn appends a handful
     of rows into buffers sized for the whole epoch budget — so the per-turn
@@ -585,83 +515,16 @@ def run_hot(
     remaining op is per-row, so hot and cold agree float-for-float, not
     just decision-for-decision (tests/test_median_hot.py pins both).
 
-    ``mesh`` (a 1-D ("data",) mesh, see ``launch.mesh.make_data_mesh``)
-    routes every per-turn dispatch through ``shard_map`` over the leading B
-    axis — B must be a multiple of the axis size (``pack_instances(...,
-    mesh=...)`` pads with born-done dummies) and the sub-batch index comes
-    shard-balanced from ``hotloop.balanced_index``.  On the mesh path
-    ``donate`` and ``overlap`` default on: donation makes the per-turn
-    scatter-back reuse the transcript buffers in place instead of copying
-    the full (B, k, cap, d) state, and the double-buffered loop dispatches
-    turn t+1 before blocking on turn t's view decode (``WIDTH_GROWTH =
-    2k+2`` rows cover the worst one-turn fill growth: the S block, the ≤2
-    reply rows from each of k-1 peers, and the pivot pair).  Both remain
-    bit-exact — MEDIAN is per-instance (no cross-shard collective) and any
-    covering width is exact.  Single-device defaults keep this path the
-    unchanged PR-5 oracle; ``donate=True``/``overlap=True`` opt in.
+    ``mesh`` shards every dispatch over the leading B axis, with
+    ``donate``/``overlap`` on by default (``hotloop.run_family``); both
+    stay bit-exact — MEDIAN is per-instance (no cross-shard collective)
+    and any covering width is exact.
     """
-    B = int(state.done.shape[0])
-    cap = int(state.wx.shape[2])
-    opts = dict(k=k, cut_kernel=cut_kernel, extremes_kernel=extremes_kernel)
-    width_growth = 2 * k + 2
-
-    if mesh is not None:
-        if not compact:
-            raise ValueError("sharded sweeps require the compacted hot path")
-        S = int(mesh.shape["data"])
-        if B % S:
-            raise ValueError(
-                f"B={B} not divisible by mesh axis {S}; pack with mesh=")
-        donate = True if donate is None else donate
-        overlap = True if overlap is None else overlap
-        data = device_put_sharded(data, mesh)
-        state = device_put_sharded(state, mesh)
-        V = jnp.asarray(V, jnp.float32)
-        full_j, sub_j = _sharded_dispatches(
-            mesh, shard_specs(data), shard_specs(state),
-            (k, cut_kernel, extremes_kernel), donate)
-
-        def dispatch_full(s, *, t, width, use_warm):
-            return full_j(data, V, s, first_turn=(t == 0), trans_width=width)
-
-        def dispatch_sub(s, idx, n_act, *, t, width, use_warm):
-            return sub_j(data, V, s, idx, n_act, first_turn=(t == 0),
-                         trans_width=width)
-
-        return hotloop.run_hot(state, k=k, max_turns=max_turns, cap=cap,
-                               host_view=_host_view,
-                               dispatch_full=dispatch_full,
-                               dispatch_sub=dispatch_sub,
-                               warm=False, compact=True,
-                               width_slack=WIDTH_SLACK,
-                               width_growth=width_growth,
-                               overlap=overlap, shards=S, stats=stats)
-
-    donate = bool(donate)
-    overlap = bool(overlap)
-    if donate:
-        # donating host numpy buffers is silently ignored — upload first so
-        # the in-place scatter actually engages
-        state = jax.tree_util.tree_map(jnp.asarray, state)
-    step_d = _step_jit_don if donate else _step_jit
-    turn_d = _hot_turn_don if donate else _hot_turn
-
-    def dispatch_full(s, *, t, width, use_warm):
-        return step_d(data, V, s, first_turn=(t == 0), trans_width=width,
-                      **opts)
-
-    def dispatch_sub(s, idx, n_act, *, t, width, use_warm):
-        return turn_d(data, V, s, idx, n_act, first_turn=(t == 0),
-                      trans_width=width, **opts)
-
-    return hotloop.run_hot(state, k=k, max_turns=max_turns, cap=cap,
-                           host_view=_host_view,
-                           dispatch_full=dispatch_full,
-                           dispatch_sub=dispatch_sub,
-                           warm=False, compact=compact,
-                           width_slack=WIDTH_SLACK,
-                           width_growth=width_growth, overlap=overlap,
-                           stats=stats)
+    return hotloop.run_family(
+        FAMILY, data, (V,), state,
+        dict(k=k, cut_kernel=cut_kernel, extremes_kernel=extremes_kernel),
+        max_turns=max_turns, compact=compact, mesh=mesh, donate=donate,
+        overlap=overlap, stats=stats)
 
 
 def run_instances(

@@ -68,7 +68,7 @@ import json
 import os
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
@@ -78,20 +78,17 @@ from repro.core.sampling import epsilon_net_size
 from repro.engine import faults as F
 from repro.engine import hotloop, median, maxmarg, unified
 from repro.engine.state import (
-    BatchCommLog,
     EngineData,
-    MaxMargState,
-    ProtocolState,
     SEL_MEDIAN,
     SELECTOR_CODES,
     SELECTOR_NAMES,
-    UnifiedState,
     _round_up,
-    maxmarg_transcript_capacity,
-    transcript_capacity,
-    unified_transcript_capacity,
 )
 from repro.kernels import ops
+
+# every pool selector and the family record that describes it
+FAMILIES = {"median": median.FAMILY, "maxmarg": maxmarg.FAMILY,
+            "unified": unified.FAMILY}
 
 # host-side slot lifecycle (the device only ever sees done flags)
 SLOT_EMPTY = 0
@@ -164,7 +161,7 @@ class PoolConfig:
     checkpoint_dir: Optional[str] = None
 
     def __post_init__(self):
-        if self.selector not in ("median", "maxmarg", "unified"):
+        if self.selector not in FAMILIES:
             raise ValueError(f"unknown selector {self.selector!r}")
         if self.selector == "median" and self.d != 2:
             raise ValueError("MEDIAN engine is specified for R^2")
@@ -185,14 +182,7 @@ class PoolConfig:
 
     @property
     def cap(self) -> int:
-        if self.selector == "median":
-            return transcript_capacity(self.k, self.max_epochs)
-        if self.selector == "unified":
-            return unified_transcript_capacity(
-                self.k, self.max_epochs, self.max_support,
-                res_cap=int(self.res_cap or 0), has_median=(self.d == 2))
-        return maxmarg_transcript_capacity(self.k, self.max_epochs,
-                                           self.max_support)
+        return FAMILIES[self.selector].capacity(self)
 
 
 # ---------------------------------------------------------------------------
@@ -216,52 +206,36 @@ def _slot_masks(W, idx, kind):
     return mask(F.CORRUPT_NAN), mask(F.CORRUPT_FILL), mask(F.CORRUPT_COMM)
 
 
-@jax.jit
-def _corrupt_median(state: ProtocolState, idx, kind) -> ProtocolState:
+@functools.partial(jax.jit, static_argnames=("sep",))
+def _corrupt(state, idx, kind, *, sep):
     """Apply drawn corruption kinds to the rows in ``idx`` (fixed-size
     block, out-of-range tail drops).  Each kind trips exactly one
-    supervisor invariant: NaN separator, zeroed (non-monotone) fills, or a
-    comm-bit spike.  Runs *after* the turn's dispatch — delivered messages
-    were metered exactly; only the victim's own state mutates."""
+    supervisor invariant: NaN separator (the family's ``sep`` leaves),
+    zeroed (non-monotone) fills, or a comm-bit spike.  Runs *after* the
+    turn's dispatch — delivered messages were metered exactly; only the
+    victim's own state mutates."""
     m_nan, m_fill, m_comm = _slot_masks(state.done.shape[0], idx, kind)
-    return state._replace(
-        h_t=jnp.where(m_nan, jnp.nan, state.h_t),
-        h_v=jnp.where(m_nan[:, None], jnp.nan, state.h_v),
-        w_fill=jnp.where(m_fill[:, None], 0, state.w_fill),
-        comm=state.comm._replace(
+    w, b = sep
+    return state._replace(**{
+        b: jnp.where(m_nan, jnp.nan, getattr(state, b)),
+        w: jnp.where(m_nan[:, None], jnp.nan, getattr(state, w)),
+        "w_fill": jnp.where(m_fill[:, None], 0, state.w_fill),
+        "comm": state.comm._replace(
             bits=state.comm.bits
             + jnp.where(m_comm, F.COMM_SPIKE_BITS, 0).astype(jnp.int32)),
-    )
+    })
 
 
-@jax.jit
-def _corrupt_maxmarg(state: MaxMargState, idx, kind) -> MaxMargState:
-    m_nan, m_fill, m_comm = _slot_masks(state.done.shape[0], idx, kind)
-    return state._replace(
-        h_b=jnp.where(m_nan, jnp.nan, state.h_b),
-        h_w=jnp.where(m_nan[:, None], jnp.nan, state.h_w),
-        w_fill=jnp.where(m_fill[:, None], 0, state.w_fill),
-        comm=state.comm._replace(
-            bits=state.comm.bits
-            + jnp.where(m_comm, F.COMM_SPIKE_BITS, 0).astype(jnp.int32)),
-    )
-
-
-# UnifiedState shares MaxMargState's separator/transcript/comm leaf names,
-# so the maxmarg corruption body applies verbatim — jax.jit re-keys on the
-# pytree structure, giving the unified pool its own cached variant.  The
-# supervision view likewise: max w_fill is each family's ACTUAL transcript
-# fill (a SAMPLING row's reservoir fill is min(seen, res_cap), which grows
-# monotonically per hop — distinct from the hot loop's width view, which
-# inflates fills to res_cap for coverage).
-_corrupt_unified = _corrupt_maxmarg
-
-
-@jax.jit
-def _view_median(state: ProtocolState) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("sep",))
+def _view(state, *, sep) -> jnp.ndarray:
     """Supervision view as one (5, W) i32 transfer: done, converged, max
-    transcript fill, NaN-separator flag, comm bits."""
-    nan = jnp.isnan(state.h_t) | jnp.any(jnp.isnan(state.h_v), axis=1)
+    transcript fill, NaN-separator flag (the family's ``sep`` leaves), comm
+    bits.  Max w_fill is each family's ACTUAL transcript fill (a SAMPLING
+    row's reservoir fill is min(seen, res_cap), which grows monotonically
+    per hop — distinct from the hot loop's width view, which inflates fills
+    to res_cap for coverage)."""
+    w, b = (getattr(state, n) for n in sep)
+    nan = jnp.isnan(b) | jnp.any(jnp.isnan(w), axis=1)
     return jnp.stack([state.done.astype(jnp.int32),
                       state.converged.astype(jnp.int32),
                       jnp.max(state.w_fill, axis=1),
@@ -269,119 +243,12 @@ def _view_median(state: ProtocolState) -> jnp.ndarray:
                       state.comm.bits])
 
 
-@jax.jit
-def _view_maxmarg(state: MaxMargState) -> jnp.ndarray:
-    nan = jnp.isnan(state.h_b) | jnp.any(jnp.isnan(state.h_w), axis=1)
-    return jnp.stack([state.done.astype(jnp.int32),
-                      state.converged.astype(jnp.int32),
-                      jnp.max(state.w_fill, axis=1),
-                      nan.astype(jnp.int32),
-                      state.comm.bits])
-
-
-_view_unified = _view_maxmarg
-
-
-# ---------------------------------------------------------------------------
-# fresh-row templates (host numpy; scattered on admission)
-# ---------------------------------------------------------------------------
-
-
-def _fresh_state_median(A: int, cfg: PoolConfig, live: int) -> ProtocolState:
-    m, k, cap = cfg.n_angles, cfg.k, cfg.cap
-    done = np.zeros((A,), bool)
-    done[live:] = True                    # block padding rows are born done
-    return ProtocolState(
-        dir_ok=np.ones((A, m), bool),
-        wx=np.zeros((A, k, cap, 2), np.float32),
-        wy=np.zeros((A, k, cap), np.int32),
-        w_fill=np.zeros((A, k), np.int32),
-        lo_w=np.full((A, k, m), -np.inf, np.float32),
-        hi_w=np.full((A, k, m), np.inf, np.float32),
-        turn=np.zeros((A,), np.int32),
-        done=done,
-        converged=np.zeros((A,), bool),
-        epochs=np.zeros((A,), np.int32),
-        h_v=np.zeros((A, 2), np.float32),
-        h_t=np.zeros((A,), np.float32),
-        h_valid=np.zeros((A,), bool),
-        comm=BatchCommLog(*(np.zeros((A,), np.int32)
-                            for _ in BatchCommLog._fields)),
-    )
-
-
-def _fresh_state_maxmarg(A: int, cfg: PoolConfig, live: int) -> MaxMargState:
-    k, cap, d = cfg.k, cfg.cap, cfg.d
-    done = np.zeros((A,), bool)
-    done[live:] = True
-    return MaxMargState(
-        wx=np.zeros((A, k, cap, d), np.float32),
-        wy=np.zeros((A, k, cap), np.int32),
-        w_fill=np.zeros((A, k), np.int32),
-        turn=np.zeros((A,), np.int32),
-        done=done,
-        converged=np.zeros((A,), bool),
-        epochs=np.zeros((A,), np.int32),
-        h_w=np.zeros((A, d), np.float32),
-        h_b=np.zeros((A,), np.float32),
-        h_valid=np.zeros((A,), bool),
-        warm_turn=np.zeros((A,), bool),
-        c_w=np.zeros((A, k, d), np.float32),
-        c_b=np.zeros((A, k), np.float32),
-        c_valid=np.zeros((A, k), bool),
-        warm_node=np.zeros((A, k), bool),
-        latches=np.zeros((A,), np.int32),
-        comm=BatchCommLog(*(np.zeros((A,), np.int32)
-                            for _ in BatchCommLog._fields)),
-    )
-
-
-def _fresh_state_unified(A: int, cfg: PoolConfig, live: int,
-                         batch: Sequence["_Pending"] = ()) -> UnifiedState:
-    """Fresh superset rows for a mixed admission wave: the selector code,
-    reservoir size and Vitter hop keys are per-row data taken from the
-    pending entries — the device tree shapes (and so the admission scatter's
-    compile key) never depend on the wave's selector mix."""
-    k, cap, d = cfg.k, cfg.cap, cfg.d
-    m = cfg.n_angles if d == 2 else 1
-    done = np.zeros((A,), bool)
-    done[live:] = True
-    sel = np.zeros((A,), np.int32)
-    res_cap = np.zeros((A,), np.int32)
-    hop_keys = np.zeros((A, max(k - 1, 1), 2), np.uint32)
-    for i, p in enumerate(batch):
-        sel[i] = SELECTOR_CODES[p.selector]
-        if p.selector == "sampling":
-            res_cap[i] = p.res_cap
-            hop_keys[i] = np.asarray(jax.random.split(
-                jax.random.PRNGKey(p.seed), k - 1))
-    return UnifiedState(
-        sel=sel,
-        dir_ok=np.ones((A, m), bool),
-        lo_w=np.full((A, k, m), -np.inf, np.float32),
-        hi_w=np.full((A, k, m), np.inf, np.float32),
-        wx=np.zeros((A, k, cap, d), np.float32),
-        wy=np.zeros((A, k, cap), np.int32),
-        w_fill=np.zeros((A, k), np.int32),
-        turn=np.zeros((A,), np.int32),
-        done=done,
-        converged=np.zeros((A,), bool),
-        epochs=np.zeros((A,), np.int32),
-        h_w=np.zeros((A, d), np.float32),
-        h_b=np.zeros((A,), np.float32),
-        h_valid=np.zeros((A,), bool),
-        warm_turn=np.zeros((A,), bool),
-        c_w=np.zeros((A, k, d), np.float32),
-        c_b=np.zeros((A, k), np.float32),
-        c_valid=np.zeros((A, k), bool),
-        warm_node=np.zeros((A, k), bool),
-        latches=np.zeros((A,), np.int32),
-        seen=np.zeros((A,), np.int32),
-        res_cap=res_cap,
-        hop_keys=hop_keys,
-        comm=BatchCommLog(*(np.zeros((A,), np.int32)
-                            for _ in BatchCommLog._fields)),
-    )
+def _flat_items(tree):
+    """``(key, leaf)`` for every leaf of ``tree``, keyed by its path joined
+    with "/": the names a checkpoint's ``.npz`` stores leaves under."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return [("/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                      for k in path), leaf) for path, leaf in flat]
 
 
 @dataclasses.dataclass
@@ -447,23 +314,22 @@ class SessionPool:
                                if config.solver_kernel is None
                                else bool(config.solver_kernel))
         W, k, n_pad, d = config.slots, config.k, config.n_pad, config.d
-
-        if config.selector == "median":
-            from repro.core import geometry as geo
-            self._V = jnp.asarray(geo.direction_grid(config.n_angles),
-                                  jnp.float32)
-            state0 = _fresh_state_median(W, config, live=0)
-        elif config.selector == "unified":
-            if config.d == 2:
+        self.family = fam = FAMILIES[config.selector]
+        self._consts = ()
+        if fam.takes_grid:
+            if d == 2:
                 from repro.core import geometry as geo
-                self._V = jnp.asarray(geo.direction_grid(config.n_angles),
-                                      jnp.float32)
+                V = geo.direction_grid(config.n_angles)
             else:   # median-free pool: stub grid (the substep is omitted)
-                self._V = jnp.zeros((1, config.d), jnp.float32)
-            state0 = _fresh_state_unified(W, config, live=0)
-        else:
-            self._V = None
-            state0 = _fresh_state_maxmarg(W, config, live=0)
+                V = np.zeros((1, d))
+            self._consts = (jnp.asarray(V, jnp.float32),)
+        # the pinned turn's keywords: every turn is a mid-phase turn at the
+        # full transcript width, cold
+        self._turn_kw = dict(
+            fam.pool_opts(config, self._solver_kernel),
+            **fam.turn_kw(first_turn=False, trans_width=config.cap,
+                          warm=False))
+        state0 = fam.fresh_state(W, config, 0, ())
         self.data = EngineData(
             jnp.zeros((W, k, n_pad, d), jnp.float32),
             jnp.zeros((W, k, n_pad), jnp.int32),
@@ -619,12 +485,7 @@ class SessionPool:
                                              np.int32)]),
                     np.concatenate([dblk.budget,
                                     np.zeros((A - take,), np.int32)]))
-            if cfg.selector == "median":
-                fresh = _fresh_state_median(A, cfg, live=take)
-            elif cfg.selector == "unified":
-                fresh = _fresh_state_unified(A, cfg, live=take, batch=batch)
-            else:
-                fresh = _fresh_state_maxmarg(A, cfg, live=take)
+            fresh = self.family.fresh_state(A, cfg, take, batch)
             idx = np.full((A,), W, np.int32)
             idx[:take] = slots
             self.data, self.state = _admit_rows(
@@ -682,34 +543,18 @@ class SessionPool:
         ``(jitted turn, operands, static keywords)``: ``fn(*args, **kw)``
         is the turn ``_dispatch`` runs, and ``fn.lower(*args, **kw)`` is
         the program every pool turn of this pool executes."""
-        cfg = self.cfg
-        W = cfg.slots
+        W = self.cfg.slots
         idx = np.full((_round_up(W, hotloop.BATCH_MULT),), W, np.int32)
         idx[:rows.size] = rows
-        tail = (self.state, jnp.asarray(idx), jnp.int32(rows.size))
-        width = cfg.cap
-        if cfg.selector == "median":
-            return median._hot_turn, (self.data, self._V) + tail, dict(
-                k=cfg.k, first_turn=False, cut_kernel=False,
-                extremes_kernel=False, trans_width=width)
-        solver = dict(k=cfg.k, max_support=cfg.max_support,
-                      steps=cfg.svm_steps, stages=cfg.svm_stages,
-                      lam0=cfg.lam0, trans_width=width, warm=False,
-                      per_node=False, fused_kernel=False,
-                      solver_kernel=self._solver_kernel)
-        if cfg.selector == "unified":
-            return unified._hot_turn, (self.data, self._V) + tail, dict(
-                solver, has_median=(cfg.d == 2), first_turn=False,
-                cut_kernel=False, extremes_kernel=False)
-        return maxmarg._hot_turn, (self.data,) + tail, solver
+        args = (self.data, *self._consts, self.state, jnp.asarray(idx),
+                jnp.int32(rows.size))
+        return hotloop.programs(self.family)[1], args, dict(self._turn_kw)
 
     def _corrupt(self, rows: np.ndarray, kinds: np.ndarray):
         """Post-turn corruption wave at the pinned ``corrupt_block`` shape
         (multiple waves if the draw hit more rows than one block holds)."""
         C = self.cfg.corrupt_block
         W = self.cfg.slots
-        fn = {"median": _corrupt_median, "maxmarg": _corrupt_maxmarg,
-              "unified": _corrupt_unified}[self.cfg.selector]
         for off in range(0, rows.size, C):
             idx = np.full((C,), W, np.int32)
             knd = np.full((C,), -1, np.int32)
@@ -717,7 +562,8 @@ class SessionPool:
             take = rows[chunk].size
             idx[:take] = rows[chunk]
             knd[:take] = kinds[chunk]
-            self.state = fn(self.state, jnp.asarray(idx), jnp.asarray(knd))
+            self.state = _corrupt(self.state, jnp.asarray(idx),
+                                  jnp.asarray(knd), sep=self.family.sep)
 
     def _quarantine(self, slot: int, reason: str):
         self.slot_state[slot] = SLOT_QUARANTINED
@@ -735,16 +581,11 @@ class SessionPool:
 
         cfg = self.cfg
         s = self.state
-        if cfg.selector == "median":
-            w_np = -np.asarray(s.h_v, np.float64)
-            b_np = np.asarray(s.h_t, np.float64)
-        else:
-            w_np = np.asarray(s.h_w, np.float64)
-            b_np = np.asarray(s.h_b, np.float64)
-            if cfg.selector == "unified":
-                # shared-leaf convention: MEDIAN rows store h_v in h_w and
-                # recover LinearSeparator(-h_v, h_t) at extraction
-                w_np[self.slot_sel == SEL_MEDIAN] *= -1.0
+        w_np, b_np = (np.asarray(getattr(s, n), np.float64)
+                      for n in self.family.sep)
+        # MEDIAN rows hold the cut direction h_v: their separator is
+        # LinearSeparator(-h_v, h_t)
+        w_np[self.slot_sel == SEL_MEDIAN] *= -1.0
         epochs = np.asarray(s.epochs)
         conv = np.asarray(s.converged)
         comm_np = type(s.comm)(*(np.asarray(a) for a in s.comm))
@@ -759,8 +600,7 @@ class SessionPool:
                 self.stats["evicted_converged" if converged
                            else "evicted_budget"] += 1
                 h = clf.LinearSeparator(w_np[slot], float(b_np[slot]))
-                sel_name = (SELECTOR_NAMES[int(self.slot_sel[slot])]
-                            if cfg.selector == "unified" else cfg.selector)
+                sel_name = SELECTOR_NAMES[int(self.slot_sel[slot])]
                 self.results[sid] = ProtocolResult(
                     h,
                     comm_np.summary(int(slot), dim=cfg.d),
@@ -852,10 +692,8 @@ class SessionPool:
                     self.sessions[self.sid[slot]]["corrupt_kind"] = int(kind)
 
         # -- supervision screen (one (5, W) transfer) -----------------------
-        viewer = {"median": _view_median, "maxmarg": _view_maxmarg,
-                  "unified": _view_unified}[cfg.selector]
         with jax.profiler.TraceAnnotation("pool.view"):
-            view = np.asarray(viewer(self.state))
+            view = np.asarray(_view(self.state, sep=self.family.sep))
         done, conv, fills, nan, bits = view
         live = self.slot_state == SLOT_LIVE       # minus fresh quarantines
 
@@ -928,10 +766,9 @@ class SessionPool:
         ``.npz`` plus a JSON manifest (the ``train/checkpoint.py`` idiom).
         The fault schedule is stateless, so the snapshot fully determines
         the remaining run."""
-        from repro.train.checkpoint import _flatten
-
         os.makedirs(dirname, exist_ok=True)
-        flat = _flatten({"data": self.data, "state": self.state})
+        flat = {key: np.asarray(leaf) for key, leaf in _flat_items(
+            {"data": self.data, "state": self.state})}
         flat.update({
             "host/sid": self.sid, "host/slot_state": self.slot_state,
             "host/retries": self.retries,
@@ -995,10 +832,8 @@ class SessionPool:
         z = np.load(man["path"])
 
         def leaf(tree, prefix):
-            flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-            keys = ["/".join(str(getattr(kk, "key", getattr(kk, "idx", kk)))
-                             for kk in path) for path, _ in flat]
-            vals = [jnp.asarray(z[f"{prefix}/{key}"]) for key in keys]
+            vals = [jnp.asarray(z[f"{prefix}/{key}"])
+                    for key, _ in _flat_items(tree)]
             return jax.tree_util.tree_unflatten(
                 jax.tree_util.tree_structure(tree), vals)
 

@@ -185,6 +185,59 @@ class ProtocolInstance:
     seed: int = 0
 
 
+def _blank_comm(B: int) -> BatchCommLog:
+    return BatchCommLog(*(np.zeros((B,), np.int32)
+                          for _ in BatchCommLog._fields))
+
+
+def blank_protocol_state(B: int, k: int, cap: int, m: int,
+                         done: np.ndarray) -> ProtocolState:
+    """B fresh MEDIAN rows as host arrays: empty transcripts, all m grid
+    directions allowed, unbounded thresholds; ``done`` (B,) bool marks the
+    rows born done (padding)."""
+    return ProtocolState(
+        dir_ok=np.ones((B, m), bool),
+        wx=np.zeros((B, k, cap, 2), np.float32),
+        wy=np.zeros((B, k, cap), np.int32),
+        w_fill=np.zeros((B, k), np.int32),
+        lo_w=np.full((B, k, m), -np.inf, np.float32),
+        hi_w=np.full((B, k, m), np.inf, np.float32),
+        turn=np.zeros((B,), np.int32),
+        done=done,
+        converged=np.zeros((B,), bool),
+        epochs=np.zeros((B,), np.int32),
+        h_v=np.zeros((B, 2), np.float32),
+        h_t=np.zeros((B,), np.float32),
+        h_valid=np.zeros((B,), bool),
+        comm=_blank_comm(B),
+    )
+
+
+def blank_maxmarg_state(B: int, k: int, cap: int, d: int,
+                        done: np.ndarray) -> MaxMargState:
+    """B fresh MAXMARG rows as host arrays: empty transcripts, no
+    proposal, no carries; ``done`` marks the rows born done."""
+    return MaxMargState(
+        wx=np.zeros((B, k, cap, d), np.float32),
+        wy=np.zeros((B, k, cap), np.int32),
+        w_fill=np.zeros((B, k), np.int32),
+        turn=np.zeros((B,), np.int32),
+        done=done,
+        converged=np.zeros((B,), bool),
+        epochs=np.zeros((B,), np.int32),
+        h_w=np.zeros((B, d), np.float32),
+        h_b=np.zeros((B,), np.float32),
+        h_valid=np.zeros((B,), bool),
+        warm_turn=np.zeros((B,), bool),
+        c_w=np.zeros((B, k, d), np.float32),
+        c_b=np.zeros((B, k), np.float32),
+        c_valid=np.zeros((B, k), bool),
+        warm_node=np.zeros((B, k), bool),
+        latches=np.zeros((B,), np.int32),
+        comm=_blank_comm(B),
+    )
+
+
 def _round_up(x: int, mult: int) -> int:
     return ((x + mult - 1) // mult) * mult
 
@@ -414,26 +467,7 @@ def pack_instances_maxmarg(
     # at the first dispatch like any jit input, without one eager device op
     # per field (a dozen tiny dispatches of pure overhead per sweep
     # otherwise); with one, they are made on the devices in one program
-    state0 = MaxMargState(
-        wx=np.zeros((B, k, cap, d), np.float32),
-        wy=np.zeros((B, k, cap), np.int32),
-        w_fill=np.zeros((B, k), np.int32),
-        turn=np.zeros((B,), np.int32),
-        done=done0,
-        converged=np.zeros((B,), bool),
-        epochs=np.zeros((B,), np.int32),
-        h_w=np.zeros((B, d), np.float32),
-        h_b=np.zeros((B,), np.float32),
-        h_valid=np.zeros((B,), bool),
-        warm_turn=np.zeros((B,), bool),
-        c_w=np.zeros((B, k, d), np.float32),
-        c_b=np.zeros((B, k), np.float32),
-        c_valid=np.zeros((B, k), bool),
-        warm_node=np.zeros((B, k), bool),
-        latches=np.zeros((B,), np.int32),
-        comm=BatchCommLog(*(np.zeros((B,), np.int32)
-                            for _ in BatchCommLog._fields)),
-    )
+    state0 = blank_maxmarg_state(B, k, cap, d, done0)
     if mesh is None:
         return EngineData(X, y, jnp.asarray(budget)), state0, k, cap
     lanes = NamedSharding(mesh, _batch_spec(1))
@@ -500,23 +534,7 @@ def pack_instances(
     done0[len(instances):] = True                    # born-done mesh padding
 
     data = EngineData(X, y, budget)
-    state0 = ProtocolState(
-        dir_ok=np.ones((B, n_angles), bool),
-        wx=np.zeros((B, k, cap, 2), np.float32),
-        wy=np.zeros((B, k, cap), np.int32),
-        w_fill=np.zeros((B, k), np.int32),
-        lo_w=np.full((B, k, n_angles), -np.inf, np.float32),
-        hi_w=np.full((B, k, n_angles), np.inf, np.float32),
-        turn=np.zeros((B,), np.int32),
-        done=done0,
-        converged=np.zeros((B,), bool),
-        epochs=np.zeros((B,), np.int32),
-        h_v=np.zeros((B, 2), np.float32),
-        h_t=np.zeros((B,), np.float32),
-        h_valid=np.zeros((B,), bool),
-        comm=BatchCommLog(*(np.zeros((B,), np.int32)
-                            for _ in BatchCommLog._fields)),
-    )
+    state0 = blank_protocol_state(B, k, cap, n_angles, done0)
     if mesh is not None:
         return (device_put_sharded(data, mesh),
                 device_put_sharded(state0, mesh), k, cap)
@@ -587,6 +605,22 @@ class UnifiedState(NamedTuple):
     res_cap: jnp.ndarray    # (B,) i32 — per-instance ε-net reservoir size
     hop_keys: jnp.ndarray   # (B, k-1, 2) u32 — per-hop Vitter PRNG keys
     comm: BatchCommLog
+
+
+def blank_unified_state(B: int, k: int, cap: int, d: int, m: int,
+                        done: np.ndarray, sel: np.ndarray,
+                        res_cap: np.ndarray,
+                        hop_keys: np.ndarray) -> UnifiedState:
+    """B fresh mixed rows as host arrays: the selector codes ``sel``, the
+    SAMPLING reservoir sizes ``res_cap`` and Vitter keys ``hop_keys`` are
+    per-row data, every other leaf blank as in the per-family states (m
+    arc directions, or 1 for a median-free mix)."""
+    return UnifiedState(
+        sel=sel, dir_ok=np.ones((B, m), bool),
+        lo_w=np.full((B, k, m), -np.inf, np.float32),
+        hi_w=np.full((B, k, m), np.inf, np.float32),
+        **blank_maxmarg_state(B, k, cap, d, done)._asdict(),
+        seen=np.zeros((B,), np.int32), res_cap=res_cap, hop_keys=hop_keys)
 
 
 def unified_transcript_capacity(k: int, max_epochs: int, max_support: int,
@@ -671,32 +705,9 @@ def pack_instances_unified(
             n_total += n
         budget[b] = int(np.floor(inst.eps * n_total))
 
-    state0 = UnifiedState(
-        sel=np.asarray([SELECTOR_CODES[s] for s in sels], np.int32),
-        dir_ok=np.ones((B, m), bool),
-        lo_w=np.full((B, k, m), -np.inf, np.float32),
-        hi_w=np.full((B, k, m), np.inf, np.float32),
-        wx=np.zeros((B, k, cap, d), np.float32),
-        wy=np.zeros((B, k, cap), np.int32),
-        w_fill=np.zeros((B, k), np.int32),
-        turn=np.zeros((B,), np.int32),
-        done=np.zeros((B,), bool),
-        converged=np.zeros((B,), bool),
-        epochs=np.zeros((B,), np.int32),
-        h_w=np.zeros((B, d), np.float32),
-        h_b=np.zeros((B,), np.float32),
-        h_valid=np.zeros((B,), bool),
-        warm_turn=np.zeros((B,), bool),
-        c_w=np.zeros((B, k, d), np.float32),
-        c_b=np.zeros((B, k), np.float32),
-        c_valid=np.zeros((B, k), bool),
-        warm_node=np.zeros((B, k), bool),
-        latches=np.zeros((B,), np.int32),
-        seen=np.zeros((B,), np.int32),
-        res_cap=res_cap,
-        hop_keys=hop_keys,
-        comm=BatchCommLog(*(np.zeros((B,), np.int32)
-                            for _ in BatchCommLog._fields)),
-    )
+    state0 = blank_unified_state(
+        B, k, cap, d, m, np.zeros((B,), bool),
+        np.asarray([SELECTOR_CODES[s] for s in sels], np.int32), res_cap,
+        hop_keys)
     data = EngineData(jnp.asarray(X), jnp.asarray(y), jnp.asarray(budget))
     return data, state0, k, cap

@@ -65,11 +65,14 @@ from repro.engine.state import (
     MaxMargState,
     ProtocolInstance,
     ProtocolState,
+    SELECTOR_CODES,
     SEL_MAXMARG,
     SEL_MEDIAN,
     SEL_SAMPLING,
     UnifiedState,
+    blank_unified_state,
     pack_instances_unified,
+    unified_transcript_capacity,
 )
 
 
@@ -230,8 +233,6 @@ _STEP_STATICS = ("k", "max_support", "steps", "stages", "trans_width",
                  "cut_kernel", "extremes_kernel", "fused_kernel",
                  "solver_kernel")
 
-_step_jit = jax.jit(step, static_argnames=_STEP_STATICS)
-
 
 def _pad_fix(sub: UnifiedState, pad_row: jnp.ndarray) -> UnifiedState:
     """Mark gathered out-of-range rows inert: done=True masks them out of
@@ -243,45 +244,6 @@ def _pad_fix(sub: UnifiedState, pad_row: jnp.ndarray) -> UnifiedState:
                         h_valid=sub.h_valid | pad_row,
                         c_valid=sub.c_valid | pad_row[:, None],
                         warm_node=sub.warm_node | pad_row[:, None])
-
-
-def _hot_turn_impl(
-    data: EngineData,
-    V: jnp.ndarray,
-    state: UnifiedState,
-    idx: jnp.ndarray,       # (n_pad,) i32 — active rows, tail = B (dropped)
-    n_act: jnp.ndarray,     # () i32 — live prefix of idx
-    *,
-    k: int,
-    max_support: int,
-    steps: int,
-    stages: int,
-    lam0: float,
-    trans_width: int,
-    warm: bool,
-    per_node: bool,
-    has_median: bool,
-    first_turn: bool,
-    cut_kernel: bool,
-    extremes_kernel: bool,
-    fused_kernel: bool,
-    solver_kernel: Optional[bool] = None,
-) -> UnifiedState:
-    """One compacted mixed turn as a single dispatch (gather → pad-fix →
-    step → scatter, ``hotloop.gathered_turn``); V passes through ungathered
-    like the MEDIAN hot turn."""
-    step_fn = functools.partial(
-        step, k=k, max_support=max_support, steps=steps, stages=stages,
-        lam0=lam0, trans_width=trans_width, warm=warm, per_node=per_node,
-        has_median=has_median, first_turn=first_turn, cut_kernel=cut_kernel,
-        extremes_kernel=extremes_kernel, fused_kernel=fused_kernel,
-        solver_kernel=solver_kernel)
-    return hotloop.gathered_turn(
-        lambda sub_data, sub: step_fn(sub_data, V, sub),
-        _pad_fix, data, state, idx, n_act)
-
-
-_hot_turn = jax.jit(_hot_turn_impl, static_argnames=_STEP_STATICS)
 
 
 @functools.partial(jax.jit, static_argnames=("per_node",))
@@ -308,6 +270,50 @@ def _host_view(state: UnifiedState, ci: jnp.ndarray, *,
                       fills])
 
 
+def _fresh_state(A: int, cfg, live: int, batch) -> UnifiedState:
+    """Fresh superset rows for a mixed admission wave: the selector code,
+    reservoir size and Vitter hop keys are per-row data taken from the
+    pending entries — the device tree shapes (and so the admission scatter's
+    compile key) never depend on the wave's selector mix."""
+    k, d = cfg.k, cfg.d
+    sel = np.zeros((A,), np.int32)
+    res_cap = np.zeros((A,), np.int32)
+    hop_keys = np.zeros((A, max(k - 1, 1), 2), np.uint32)
+    for i, p in enumerate(batch):
+        sel[i] = SELECTOR_CODES[p.selector]
+        if p.selector == "sampling":
+            res_cap[i] = p.res_cap
+            hop_keys[i] = np.asarray(jax.random.split(
+                jax.random.PRNGKey(p.seed), k - 1))
+    return blank_unified_state(A, k, cfg.cap, d,
+                               cfg.n_angles if d == 2 else 1,
+                               np.arange(A) >= live, sel, res_cap, hop_keys)
+
+
+# one loop drives all three families at once: the width slack and the
+# stale-view growth bound are the *max* over the families' own bounds
+# (MEDIAN's post-S extremes slack, MAXMARG's support/violation appends), so
+# every compacted read covers whichever family's transcript grew fastest;
+# the pool reads MEDIAN rows exactly where the grid exists (d = 2)
+FAMILY = hotloop.Family(
+    step=step, statics=_STEP_STATICS, pad_fix=_pad_fix,
+    host_view=lambda state, ci, opts: _host_view(
+        state, ci, per_node=opts["per_node"]),
+    width_slack=lambda opts: median.WIDTH_SLACK if opts["has_median"] else 0,
+    width_growth=lambda opts: max(median.FAMILY.width_growth(opts),
+                                  mm.FAMILY.width_growth(opts)),
+    takes_grid=True,
+    capacity=lambda cfg: unified_transcript_capacity(
+        cfg.k, cfg.max_epochs, cfg.max_support,
+        res_cap=int(cfg.res_cap or 0), has_median=(cfg.d == 2)),
+    pool_opts=lambda cfg, solver_kernel: dict(
+        mm.FAMILY.pool_opts(cfg, solver_kernel), has_median=(cfg.d == 2),
+        cut_kernel=False, extremes_kernel=False),
+    fresh_state=_fresh_state,
+    sep=("h_w", "h_b"),
+)
+
+
 def run_hot(
     data: EngineData,
     V: jnp.ndarray,
@@ -331,44 +337,20 @@ def run_hot(
     stats: Optional[dict] = None,
 ) -> UnifiedState:
     """The mixed sweep as a host-driven turn loop over the jitted ``step``
-    (the shared machinery in :mod:`repro.engine.hotloop`).
+    (:func:`repro.engine.hotloop.run_family` over :data:`FAMILY`).
 
-    One loop drives all three families at once: the width slack and the
-    stale-view growth bound are the *max* over the families' own bounds
-    (MEDIAN's post-S extremes slack, MAXMARG's support/violation appends),
-    so every compacted read covers whichever family's transcript grew
-    fastest.  ``width_policy`` defaults to ``"geometric"`` here — mixed
-    traffic spreads live fills across families with very different growth
-    rates, exactly the churn case the geometric buckets bound — while the
+    ``width_policy`` defaults to ``"geometric"`` here — mixed traffic
+    spreads live fills across families with very different growth rates,
+    exactly the churn case the geometric buckets bound — while the
     per-selector loops keep their linear (byte-identical legacy) rule.
     """
-    cap = int(state.wx.shape[2])
-    track = per_node and warm
     opts = dict(k=k, max_support=max_support, steps=steps, stages=stages,
-                lam0=lam0, per_node=track, has_median=has_median,
+                lam0=lam0, per_node=per_node and warm, has_median=has_median,
                 cut_kernel=cut_kernel, extremes_kernel=extremes_kernel,
                 fused_kernel=fused_kernel, solver_kernel=solver_kernel)
-    width_slack = median.WIDTH_SLACK if has_median else 0
-    width_growth = max(2 * k + 2, max_support, mm.VIOL_SHIP * (k - 1))
-
-    def host_view(s, ci):
-        return _host_view(s, ci, per_node=track)
-
-    def dispatch_full(s, *, t, width, use_warm):
-        return _step_jit(data, V, s, first_turn=(t == 0),
-                         trans_width=width, warm=use_warm, **opts)
-
-    def dispatch_sub(s, idx, n_act, *, t, width, use_warm):
-        return _hot_turn(data, V, s, idx, n_act, first_turn=(t == 0),
-                         trans_width=width, warm=use_warm, **opts)
-
-    return hotloop.run_hot(state, k=k, max_turns=max_turns, cap=cap,
-                           host_view=host_view,
-                           dispatch_full=dispatch_full,
-                           dispatch_sub=dispatch_sub, warm=warm,
-                           compact=compact, width_slack=width_slack,
-                           width_growth=width_growth,
-                           width_policy=width_policy, stats=stats)
+    return hotloop.run_family(
+        FAMILY, data, (V,), state, opts, max_turns=max_turns, warm=warm,
+        compact=compact, width_policy=width_policy, stats=stats)
 
 
 def run_instances(

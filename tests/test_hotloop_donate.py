@@ -28,7 +28,7 @@ import jax.numpy as jnp
 
 from repro import engine
 from repro.core import datasets, geometry as geo
-from repro.engine import median, maxmarg
+from repro.engine import hotloop, median, maxmarg
 
 N_ANGLES = 256
 MAX_EPOCHS = 24
@@ -50,9 +50,10 @@ def test_use_after_donate_raises():
     data, st, k, cap = engine.pack_instances(
         insts, n_angles=N_ANGLES, max_epochs=MAX_EPOCHS)
     V = jnp.asarray(geo.direction_grid(N_ANGLES), jnp.float32)
-    out = median._step_jit_don(data, V, st, k=k, first_turn=True,
-                               cut_kernel=False, extremes_kernel=False,
-                               trans_width=8)
+    step_don, step_plain = (hotloop.programs(median.FAMILY, donate)[0]
+                            for donate in (True, False))
+    out = step_don(data, V, st, k=k, first_turn=True, cut_kernel=False,
+                   extremes_kernel=False, trans_width=8)
     jax.block_until_ready(out.wx)
     with pytest.raises((RuntimeError, ValueError)):
         np.asarray(st.wx)
@@ -60,8 +61,8 @@ def test_use_after_donate_raises():
     # first handle is dead)
     _, st2, _, _ = engine.pack_instances(
         insts, n_angles=N_ANGLES, max_epochs=MAX_EPOCHS)
-    median._step_jit(data, V, st2, k=k, first_turn=True,
-                     cut_kernel=False, extremes_kernel=False, trans_width=8)
+    step_plain(data, V, st2, k=k, first_turn=True, cut_kernel=False,
+               extremes_kernel=False, trans_width=8)
     np.asarray(st2.wx)
 
 

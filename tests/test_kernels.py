@@ -293,8 +293,9 @@ def test_maxmarg_turn_scan_on_engine_grid_bit_for_bit():
     from repro import engine
     from repro.core import datasets
     from repro.core.classifiers import _svm_solve_batch
-    from repro.engine import maxmarg as MM
+    from repro.engine import hotloop, maxmarg as MM
 
+    step_j = hotloop.programs(MM.FAMILY)[0]
     insts = [engine.ProtocolInstance(
                  datasets.data3(n_per_node=60, k=2, seed=s), 0.02, "maxmarg")
              for s in range(4)]
@@ -316,9 +317,9 @@ def test_maxmarg_turn_scan_on_engine_grid_bit_for_bit():
         want = ref.maxmarg_turn_batch_ref(w, b, K, yK, data.X, data.y)
         for g, e in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(e))
-        state = MM._step_jit(data, state, k=k, max_support=4, steps=500,
-                             stages=2, lam0=1e-3, trans_width=None,
-                             warm=False, fused_kernel=False)
+        state = step_j(data, state, k=k, max_support=4, steps=500,
+                       stages=2, lam0=1e-3, trans_width=None,
+                       warm=False, fused_kernel=False)
         if bool(jnp.all(state.done)):
             break
 

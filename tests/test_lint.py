@@ -398,16 +398,31 @@ def test_committed_manifest_excludes_only_seed_stack():
 
 def test_engine_modules_are_genuinely_scanned():
     """Zero findings must mean 'clean', not 'blind': the analyzer resolves
-    the real donating dispatches and traced steps in engine/median.py."""
+    the real donating dispatches (the turn loop's ``full_j``/``sub_j``
+    calls in engine/hotloop.py, which donate the state ``s``) and each
+    family's traced ``step`` with its static names (engine/median.py,
+    maxmarg.py, unified.py, through their ``hotloop.Family`` records)."""
     import ast as ast_mod
 
     from repro.analysis.lint.context import FileContext
+    from repro.analysis.lint.rules.r001_use_after_donate import _Walker
 
-    path = os.path.join(ROOT, "src", "repro", "engine", "median.py")
-    src = open(path, encoding="utf-8").read()
-    fc = FileContext(path, src, ast_mod.parse(src))
-    donating = {n for n, b in fc.jit_bindings.items() if b.donated_nums}
-    assert {"_step_jit_don", "_hot_turn_don", "step_d", "turn_d"} <= donating
-    traced = fc.traced_functions()
-    assert "step" in traced and traced["step"] is not None
-    assert "trans_width" in traced["step"]
+    def context(name):
+        path = os.path.join(ROOT, "src", "repro", "engine", name)
+        src = open(path, encoding="utf-8").read()
+        return FileContext(path, src, ast_mod.parse(src))
+
+    fc = context("hotloop.py")
+    walker = _Walker(fc, load_config(os.path.join(ROOT, "pyproject.toml")))
+    donating = {}
+    for node in ast_mod.walk(fc.functions["run_family"]):
+        if isinstance(node, ast_mod.Call) and isinstance(node.func,
+                                                         ast_mod.Name):
+            donated = walker._donated_in_call(node)
+            if donated:
+                donating[node.func.id] = donated
+    assert donating == {"full_j": ["s"], "sub_j": ["s"]}, donating
+    for name in ("median.py", "maxmarg.py", "unified.py"):
+        traced = context(name).traced_functions()
+        assert "step" in traced and traced["step"] is not None, name
+        assert "trans_width" in traced["step"], name

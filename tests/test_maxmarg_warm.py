@@ -177,7 +177,7 @@ def test_per_node_latch_where_single_carry_provably_falls_through():
     latch counters must differ — and every protocol decision (comm deltas,
     transcript appends, termination) must be identical across per-node,
     single-carry, and fully cold execution."""
-    from repro.engine import maxmarg as MM
+    from repro.engine import hotloop, maxmarg as MM
 
     rng = np.random.default_rng(5)
     half = 30
@@ -218,9 +218,10 @@ def test_per_node_latch_where_single_carry_provably_falls_through():
 
     opts = dict(k=k, max_support=4, steps=500, stages=2, lam0=1e-3,
                 trans_width=None, fused_kernel=False)
-    pn = MM._step_jit(data, base, warm=True, per_node=True, **opts)
-    sg = MM._step_jit(data, base, warm=True, per_node=False, **opts)
-    cold = MM._step_jit(data, base, warm=False, per_node=True, **opts)
+    step_j = hotloop.programs(MM.FAMILY)[0]
+    pn = step_j(data, base, warm=True, per_node=True, **opts)
+    sg = step_j(data, base, warm=True, per_node=False, **opts)
+    cold = step_j(data, base, warm=False, per_node=True, **opts)
 
     assert int(pn.latches[0]) == 1          # clean carry -> polish latch
     assert int(sg.latches[0]) == 0          # dirty init -> provable gate fail

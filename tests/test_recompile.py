@@ -42,16 +42,22 @@ def _grid(n, selector="median"):
         (0.1, 0.05, 0.02)[i % 3], selector) for i in range(n)]
 
 
+def _lowerings(family):
+    """Lowerings of a family's single-device programs (full and sub turn)
+    as the factory memoises them."""
+    return sum(f._cache_size() for f in hotloop.programs(family))
+
+
 def _median_lowerings():
-    return median._step_jit._cache_size() + median._hot_turn._cache_size()
+    return _lowerings(median.FAMILY)
 
 
 def _maxmarg_lowerings():
-    return maxmarg._step_jit._cache_size() + maxmarg._hot_turn._cache_size()
+    return _lowerings(maxmarg.FAMILY)
 
 
 def _unified_lowerings():
-    return unified._step_jit._cache_size() + unified._hot_turn._cache_size()
+    return _lowerings(unified.FAMILY)
 
 
 def test_median_cache_keys_only_on_npad_width_warm():
@@ -154,13 +160,13 @@ def test_unified_pool_single_pinned_key_zero_steady_recompiles():
     run(insts)
     keys = set(hotloop.KEY_LOG)
     assert len(keys) == 1, sorted(keys)     # the single pinned dispatch key
-    n_low = unified._hot_turn._cache_size()
+    n_low = hotloop.programs(unified.FAMILY)[1]._cache_size()
     assert n_low == 1
 
     hotloop.KEY_LOG.clear()
     run(list(reversed(insts)))
     assert set(hotloop.KEY_LOG) == keys
-    assert unified._hot_turn._cache_size() == n_low, \
+    assert hotloop.programs(unified.FAMILY)[1]._cache_size() == n_low, \
         "a second mixed pool recompiled"
 
 
@@ -189,8 +195,10 @@ def test_sharded_cache_keys_stable():
     # the sweep's own arguments returns the very jits the run used
     data, state0, k, _cap = engine.pack_instances(
         insts, n_angles=N_ANGLES, max_epochs=MAX_EPOCHS, mesh=mesh)
-    full_j, sub_j = median._sharded_dispatches(
-        mesh, shard_specs(data), shard_specs(state0), (k, False, False), True)
+    opts = dict(k=k, cut_kernel=False, extremes_kernel=False)
+    full_j, sub_j = hotloop.programs(
+        median.FAMILY, True, mesh, shard_specs(data), shard_specs(state0),
+        tuple(sorted(opts.items())))
     n_low = full_j._cache_size() + sub_j._cache_size()
     assert 0 < n_low <= len(keys), (n_low, sorted(keys))
 

@@ -378,8 +378,8 @@ def test_periodic_checkpoint_from_config(tmp_path):
 
 
 def _pool_cache_entries():
-    fns = (median._hot_turn, session_pool._admit_rows,
-           session_pool._corrupt_median, session_pool._view_median,
+    fns = (hotloop.programs(median.FAMILY)[1], session_pool._admit_rows,
+           session_pool._corrupt, session_pool._view,
            session_pool._mark_done)
     return sum(f._cache_size() for f in fns)
 

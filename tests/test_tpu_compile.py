@@ -171,14 +171,14 @@ def test_maxmarg_pool_dispatch_compiles(one_chip, monkeypatch):
 @pytest.mark.parametrize("L", [32, 4])
 def test_sharded_sub_turn_compiles(topo, one_chip, monkeypatch, L):
     """The four-chip MAXMARG sweep's sub-batch turn
-    (``maxmarg._sharded_sub_turn`` over a ("data",) mesh of the described
-    2x2 host; 128 instances, 32 a chip, 8192 points a node, d=10) at a
-    local batch of L rows, with the turn-scan and Pegasos kernels on: it
-    compiles, holds the kernels, and the Pegasos stage inside each shard
-    takes the resident path."""
+    (``_sharded_sub_turn`` of ``hotloop.programs`` over a ("data",) mesh
+    of the described 2x2 host; 128 instances, 32 a chip, 8192 points a
+    node, d=10) at a local batch of L rows, with the turn-scan and Pegasos
+    kernels on: it compiles, holds the kernels, and the Pegasos stage
+    inside each shard takes the resident path."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding
-    from repro.engine import maxmarg
+    from repro.engine import hotloop, maxmarg
     from repro.engine.state import (ProtocolInstance, pack_instances_maxmarg,
                                     shard_specs)
 
@@ -202,13 +202,15 @@ def test_sharded_sub_turn_compiles(topo, one_chip, monkeypatch, L):
     jax.clear_caches()          # no trace cached with the CPU answer
     ops.PEGASOS_PATH_LOG.clear()
     try:
-        _full, sub = maxmarg._sharded_dispatches(
-            mesh, dspec, sspec, (k, 4, 2000, 3, 1e-3, True, True), True)
+        opts = dict(k=k, max_support=4, steps=2000, stages=3, lam0=1e-3,
+                    per_node=True, fused_kernel=True, solver_kernel=True)
+        _full, sub = hotloop.programs(maxmarg.FAMILY, True, mesh, dspec,
+                                      sspec, tuple(sorted(opts.items())))
         txt = sub.lower(
             shapes(data, dspec), shapes(state, sspec),
             jax.ShapeDtypeStruct((S * L,), jnp.int32, sharding=lanes),
             jax.ShapeDtypeStruct((S,), jnp.int32, sharding=lanes),
-            trans_width=width, warm=True, per_node=True).compile().as_text()
+            trans_width=width, warm=True).compile().as_text()
     finally:
         jax.clear_caches()
     assert "tpu_custom_call" in txt
